@@ -30,5 +30,5 @@ example:
 	PYTHONPATH=src $(PY) examples/multi_user_agent.py
 
 trace:
-	PYTHONPATH=src $(PY) -m repro.launch.serve_tenants --tenants 6 \
+	PYTHONPATH=src $(PY) -m repro.launch.serve_tenants --smoke --tenants 6 \
 		--capacity 512 --steps 30 --clusters 8 --cache-kb 256

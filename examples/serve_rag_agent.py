@@ -7,7 +7,10 @@ TWO-STAGE HIERARCHICAL RETRIEVAL -> augmented prompt -> batched
 prefill+decode on the generator LM. Logs the paper's per-query retrieval
 energy ledger alongside the generations.
 
-    PYTHONPATH=src python examples/serve_rag_agent.py [--requests 8]
+    PYTHONPATH=src python examples/serve_rag_agent.py [--requests 8] [--smoke]
+
+Without --smoke the generator (qwen2-0.5b) and the embedder
+(minilm-embedder) run at their FULL widths from `repro.configs`.
 """
 import argparse
 import time
@@ -27,24 +30,25 @@ def main():
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--num-docs", type=int, default=256)
     ap.add_argument("--max-new", type=int, default=16)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced generator/embedder configs (CPU)")
     args = ap.parse_args()
     rng = np.random.default_rng(0)
 
-    # generator: reduced qwen2-family LM served greedily
-    gcfg = get_config("qwen2-0.5b", smoke=True)
+    # generator: qwen2-family LM served greedily
+    gcfg = get_config("qwen2-0.5b", smoke=args.smoke)
     gen_api = get_model(gcfg)
     gen_params = gen_api.init(jax.random.PRNGKey(0))
 
     # embedder: MiniLM-style sentence encoder (the paper's)
-    ecfg = embedder.MINILM_CFG.with_(num_layers=2, d_model=64, num_heads=4,
-                                     num_kv_heads=4, d_ff=128,
-                                     vocab_size=gcfg.vocab_size,
-                                     pooled_dim=64)
+    ecfg = get_config("minilm-embedder", smoke=args.smoke)
     eparams = embedder.init_params(ecfg, jax.random.PRNGKey(1))
 
-    # offline phase: the "personal medical record" corpus (synthetic tokens)
+    # offline phase: the "personal medical record" corpus (synthetic
+    # tokens valid for both models' vocabularies)
+    vocab = min(gcfg.vocab_size, ecfg.vocab_size)
     doc_tokens = jnp.asarray(
-        rng.integers(0, gcfg.vocab_size, (args.num_docs, 12)).astype(np.int32))
+        rng.integers(0, vocab, (args.num_docs, 12)).astype(np.int32))
     t0 = time.time()
     pipe = RAGPipeline.build(ecfg, eparams, gen_api, gen_params, doc_tokens,
                              RetrievalConfig(k=2, metric="cosine"))

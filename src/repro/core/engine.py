@@ -433,8 +433,11 @@ class StageFns:
     sign_gather_resident: object
 
 
-def stage_fns(backend: str) -> StageFns:
-    if backend == "pallas":
+def stage_fns(backend: str | None) -> StageFns:
+    """The primitives for `backend`; None picks by platform (the Pallas
+    kernels on a TPU, the jnp reference elsewhere)."""
+    from repro.kernels.platform import resolve_backend
+    if resolve_backend(backend) == "pallas":
         from repro.kernels import ops as kops
 
         def _sign_gather_k(q_sign, sign_plane, block_ids, block_rows):
@@ -1301,7 +1304,8 @@ class KVCascadeConfig:
     prescreen_c0: survivors kept by the 1-bit sign prescreen (None = off;
         requires npages — the sign gather runs over the pruned pages).
     backend: "jnp" | "pallas" for the integer stages (the f32 approx and
-        exact-attend stages are shared verbatim between backends).
+        exact-attend stages are shared verbatim between backends); None
+        = the Pallas kernels on a TPU, jnp elsewhere.
     scale: softmax scale (None = hd ** -0.5).
     """
 
@@ -1309,7 +1313,7 @@ class KVCascadeConfig:
     npages: int | None = None
     page_rows: int = 8
     prescreen_c0: int | None = None
-    backend: Literal["jnp", "pallas"] = "jnp"
+    backend: Literal["jnp", "pallas"] | None = None
     scale: float | None = None
 
     def __post_init__(self):
@@ -1624,11 +1628,10 @@ def kv_cascade_stages(cfg: KVCascadeConfig) -> tuple:
     return stages + (KVApproxTopK(cfg.top_k), KVExactAttend())
 
 
-def _kv_cascade(q: jax.Array, policy: KVCachePolicy,
-                cfg: KVCascadeConfig) -> jax.Array:
-    """One decode step's staged KV attention.
-
-    q (B, 1, H, hd) against the policy's cache; returns (B, 1, H, hd)."""
+def _kv_run(q: jax.Array, policy: KVCachePolicy,
+            cfg: KVCascadeConfig) -> _KVState:
+    """Run one decode step's stages; the final state holds the selected
+    positions and the attention output."""
     b, _, h, hd = q.shape
     kh = policy.v.shape[2]
     g = h // kh
@@ -1646,10 +1649,28 @@ def _kv_cascade(q: jax.Array, policy: KVCachePolicy,
     state = _KVState()
     for stage in kv_cascade_stages(cfg):
         state = stage.run(state, ctx)
-    return state.out
+    return state
+
+
+def _kv_cascade(q: jax.Array, policy: KVCachePolicy,
+                cfg: KVCascadeConfig) -> jax.Array:
+    """One decode step's staged KV attention.
+
+    q (B, 1, H, hd) against the policy's cache; returns (B, 1, H, hd)."""
+    return _kv_run(q, policy, cfg).out
+
+
+def _kv_selection(q: jax.Array, policy: KVCachePolicy,
+                  cfg: KVCascadeConfig) -> tuple[jax.Array, jax.Array]:
+    """The cache positions one decode step attends over: (B, KH, k)
+    position ids and their validity — what the prune, prescreen and
+    approximate stages select, for comparing backends on the same data."""
+    state = _kv_run(q, policy, cfg)
+    return state.rows, state.member
 
 
 kv_decode_batched = jax.jit(_kv_cascade, static_argnames=("cfg",))
+kv_selection = jax.jit(_kv_selection, static_argnames=("cfg",))
 
 
 def kv_plan(cfg: KVCascadeConfig, *, batch: int, kv_heads: int,

@@ -171,8 +171,7 @@ class ShardedIndex:
                                               fn(q[None], msb, lsb, nrm))
             return fn(q, msb, lsb, nrm)
 
-        from repro.compat import shard_map
-        shmapped = shard_map(
+        shmapped = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(P(), row, row, row),
             out_specs=RetrievalResult(indices=P(), scores=P(),

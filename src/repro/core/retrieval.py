@@ -17,7 +17,9 @@ is a THIN wrapper over the one batched two-stage core in repro.core.engine:
 it builds the membership/window policy for its calling convention and runs
 the shared schedule. `backend="jnp"` uses pure-jnp reference math;
 `backend="pallas"` routes both scoring stages through the batch-native
-Pallas TPU kernels in repro.kernels.
+Pallas TPU kernels in repro.kernels; left unset (None) it is keyed on the
+platform — the kernels on a TPU, the jnp reference elsewhere
+(`repro.kernels.platform.resolve_backend`).
 """
 from __future__ import annotations
 
@@ -38,7 +40,7 @@ class RetrievalConfig:
     metric: Literal["cosine", "mips"] = "cosine"
     max_candidates: int = 50
     candidate_frac: float = 0.2
-    backend: Literal["jnp", "pallas"] = "jnp"
+    backend: Literal["jnp", "pallas"] | None = None
     # Stage-0 sign-plane prescreen budget: the cluster-pruned cascade
     # inserts a 1-bit sign-agreement scan between the centroid prune and
     # the INT4 scan, keeping only the top-C0 view rows per lane (clamped

@@ -26,7 +26,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.tree_util import DictKey, GetAttrKey, SequenceKey
 
-from repro.compat import mesh_from_device_array
 from repro.models.common import ModelConfig
 
 
@@ -42,7 +41,7 @@ def serving_shard_mesh(devices) -> Mesh:
     devs = list(dict.fromkeys(devices))     # de-dupe, order-preserving
     if not devs:
         raise ValueError("need at least one device")
-    return mesh_from_device_array(np.asarray(devs), ("shard",))
+    return Mesh(np.asarray(devs), ("shard",))
 
 
 def mesh_axes(mesh: Mesh) -> tuple[tuple[str, ...], str]:

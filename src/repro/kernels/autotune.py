@@ -43,6 +43,7 @@ import numpy as np
 from repro.kernels import fused_topk as _fk
 from repro.kernels import stage0_sign as _s0
 from repro.kernels import stage1_int4 as _s1
+from repro.kernels.platform import resolve_interpret
 
 SCHEMA_VERSION = 1
 
@@ -61,9 +62,8 @@ def device_signature() -> dict:
     elsewhere), but is recorded separately: it is the single biggest
     determinant of the crossover point."""
     dev = jax.devices()[0]
-    backend = jax.default_backend()
-    return {"device_kind": dev.device_kind, "backend": backend,
-            "interpret": backend != "tpu"}
+    return {"device_kind": dev.device_kind, "backend": jax.default_backend(),
+            "interpret": resolve_interpret(None)}
 
 
 def _pow2_bucket(batch: int) -> int:

@@ -2,8 +2,9 @@
 
 These expose the same signatures the pure-jnp reference engine uses
 (repro.core.retrieval stage functions), handling query even/odd packing,
-row padding to block multiples, and interpret-mode selection (interpret on
-CPU, compiled Mosaic on TPU).
+and row padding to block multiples. Each kernel picks its own mode
+(`repro.kernels.platform.resolve_interpret`: compiled Mosaic on a TPU,
+the interpreter elsewhere).
 
 Block shapes: the tunable wrappers (stage1_* matmuls and the fused top-k)
 take `block_n=None` and resolve the block at *trace time* from the
@@ -26,10 +27,6 @@ from repro.kernels import stage0_sign as _s0
 from repro.kernels import stage1_gather as _sg
 from repro.kernels import stage1_int4 as _s1
 from repro.kernels import stage2_int8 as _s2
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def pack_query_even_odd(q: jax.Array) -> jax.Array:
@@ -55,6 +52,19 @@ def pack_query_signs(q: jax.Array) -> jax.Array:
     matching `bitplanar.unpack_sign_pm1` of the packed doc plane."""
     from repro.core.bitplanar import sign_pm1
     return sign_pm1(q)
+
+
+def sign_bit_panels(q_sign: jax.Array, *, per_lane: bool = False
+                    ) -> jax.Array:
+    """(B, D) int8 {+1, -1} -> the stage-0 kernels' bit panels: panel b
+    holds dims k = 8 * j + b, so bit b of packed sign byte j pairs with
+    column j. Returns (8, B, D//8) (the batched kernel's shared panel) or,
+    with per_lane, (B, 8, 1, D//8) (the gather kernel's per-lane block)."""
+    b, d = q_sign.shape
+    bits = q_sign.reshape(b, d // 8, 8)
+    if per_lane:
+        return bits.transpose(0, 2, 1)[:, :, None, :]
+    return bits.transpose(2, 0, 1)
 
 
 def _pad_rows(a: jax.Array, mult: int) -> jax.Array:
@@ -91,8 +101,7 @@ def _stage1_scores_jit(q_msb: jax.Array, msb_plane: jax.Array,
     block_n = min(block_n, max(8, n))
     plane = _pad_rows(msb_plane, block_n)
     q_eo = pack_query_even_odd(q_msb)
-    out = _s1.stage1_int4_pallas(q_eo, plane, block_n=block_n,
-                                 interpret=_interpret())
+    out = _s1.stage1_int4_pallas(q_eo, plane, block_n=block_n)
     return out[:n]
 
 
@@ -110,8 +119,7 @@ def stage2_scores(q: jax.Array, msb_rows: jax.Array, lsb_rows: jax.Array,
     msb = _pad_rows(msb_rows, block_c)
     lsb = _pad_rows(lsb_rows, block_c)
     q_eo8 = pack_query_even_odd(q)
-    out = _s2.stage2_int8_pallas(q_eo8, msb, lsb, block_c=block_c,
-                                 interpret=_interpret())
+    out = _s2.stage2_int8_pallas(q_eo8, msb, lsb, block_c=block_c)
     return out[:c]
 
 
@@ -138,8 +146,7 @@ def _stage1_scores_batched_jit(q_msb: jax.Array, msb_plane: jax.Array,
     block_n = min(block_n, max(8, n))
     plane = _pad_rows(msb_plane, block_n)
     q_panel = pack_query_panel(q_msb)
-    out = _s1.stage1_int4_batched_pallas(q_panel, plane, block_n=block_n,
-                                         interpret=_interpret())
+    out = _s1.stage1_int4_batched_pallas(q_panel, plane, block_n=block_n)
     return out[:, :n]
 
 
@@ -163,8 +170,7 @@ def _stage1_scores_rows_jit(q_msb: jax.Array, msb_rows: jax.Array,
     block_w = min(block_w, max(8, w))
     rows = _pad_axis1(msb_rows, block_w)
     q_eo = pack_queries_even_odd(q_msb)
-    out = _s1.stage1_int4_rows_pallas(q_eo, rows, block_w=block_w,
-                                      interpret=_interpret())
+    out = _s1.stage1_int4_rows_pallas(q_eo, rows, block_w=block_w)
     return out[:, :w]
 
 
@@ -189,8 +195,7 @@ def stage1_scores_gather(q_msb: jax.Array, msb_plane: jax.Array,
     plane = _pad_rows(msb_plane, block_rows)
     q_eo = pack_queries_even_odd(q_msb)
     return _sg.stage1_int4_gather_pallas(q_eo, plane, block_ids,
-                                         block_rows=block_rows,
-                                         interpret=_interpret())
+                                         block_rows=block_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows",))
@@ -214,8 +219,7 @@ def stage1_scores_gather_resident(q_msb: jax.Array, plane: jax.Array,
                          f"{n} rows with block_rows={block_rows}")
     q_eo = pack_queries_even_odd(q_msb)
     return _sg.stage1_int4_gather_pallas(q_eo, plane, block_ids,
-                                         block_rows=block_rows,
-                                         interpret=_interpret())
+                                         block_rows=block_rows)
 
 
 def stage0_sign_scores_batched(q_sign: jax.Array, sign_plane: jax.Array,
@@ -239,8 +243,8 @@ def _stage0_sign_scores_batched_jit(q_sign: jax.Array, sign_plane: jax.Array,
     n = sign_plane.shape[0]
     block_n = min(block_n, max(8, n))
     plane = _pad_rows(sign_plane, block_n)
-    out = _s0.stage0_sign_batched_pallas(q_sign, plane, block_n=block_n,
-                                         interpret=_interpret())
+    out = _s0.stage0_sign_batched_pallas(sign_bit_panels(q_sign), plane,
+                                         block_n=block_n)
     return out[:, :n]
 
 
@@ -258,9 +262,9 @@ def stage0_sign_scores_gather(q_sign: jax.Array, sign_plane: jax.Array,
     sized to a block multiple); zero bytes unpack to all-+1 rows on both
     backends and are masked downstream."""
     plane = _pad_rows(sign_plane, block_rows)
-    return _s0.stage0_sign_gather_pallas(q_sign, plane, block_ids,
-                                         block_rows=block_rows,
-                                         interpret=_interpret())
+    return _s0.stage0_sign_gather_pallas(
+        sign_bit_panels(q_sign, per_lane=True), plane, block_ids,
+        block_rows=block_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows",))
@@ -275,9 +279,9 @@ def stage0_sign_scores_gather_resident(q_sign: jax.Array, plane: jax.Array,
     if n % block_rows:
         raise ValueError(f"resident sign plane must be a block multiple, "
                          f"got {n} rows with block_rows={block_rows}")
-    return _s0.stage0_sign_gather_pallas(q_sign, plane, block_ids,
-                                         block_rows=block_rows,
-                                         interpret=_interpret())
+    return _s0.stage0_sign_gather_pallas(
+        sign_bit_panels(q_sign, per_lane=True), plane, block_ids,
+        block_rows=block_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k",))
@@ -319,8 +323,7 @@ def stage2_scores_batched(q: jax.Array, msb_rows: jax.Array,
     msb = _pad_axis1(msb_rows, block_c)
     lsb = _pad_axis1(lsb_rows, block_c)
     q_eo8 = pack_queries_even_odd(q)
-    out = _s2.stage2_int8_batched_pallas(q_eo8, msb, lsb, block_c=block_c,
-                                         interpret=_interpret())
+    out = _s2.stage2_int8_batched_pallas(q_eo8, msb, lsb, block_c=block_c)
     return out[:, :c]
 
 
@@ -361,8 +364,7 @@ def _fused_candidates_batched_jit(q_msb: jax.Array, msb_plane: jax.Array,
                         constant_values=-1)           # padding rows: no owner
     q_eo = pack_queries_even_odd(q_msb)
     scores, ids = _fk.fused_topk_batched_pallas(
-        q_eo, plane, owner, tenant_ids, k=k_per_block, block_n=block_n,
-        interpret=_interpret())
+        q_eo, plane, owner, tenant_ids, k=k_per_block, block_n=block_n)
     flat_s = scores.reshape(scores.shape[0], -1)
     flat_i = ids.reshape(ids.shape[0], -1)
     flat_s = jnp.where(flat_i < n, flat_s, jnp.iinfo(jnp.int32).min)
@@ -396,8 +398,7 @@ def _fused_candidates_jit(q_msb: jax.Array, msb_plane: jax.Array, *, c: int,
     plane = _pad_rows(msb_plane, block_n)
     q_eo = pack_query_even_odd(q_msb)
     scores, ids = _fk.fused_topk_pallas(q_eo, plane, k=k_per_block,
-                                        block_n=block_n,
-                                        interpret=_interpret())
+                                        block_n=block_n)
     flat_s = scores.reshape(-1)
     flat_i = ids.reshape(-1)
     # padded rows score 0 with id >= n; mask them out

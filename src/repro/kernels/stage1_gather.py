@@ -16,7 +16,8 @@ Dataflow per grid step (i = batch lane, j = probe-block slot):
     block sweep (query-stationary, as in the dense stage-1 kernels);
   * plane block `block_ids[i, j]` streams HBM->VMEM (the data-dependent
     index_map — the only difference from the dense per-lane kernel);
-  * nibbles unpack in-register and the MAC runs as an MXU matvec.
+  * nibbles unpack in-register and the MAC runs on the MXU as a (1, D/2)
+    query row against the (block_rows, D/2) block.
 
 block_ids must be pre-clamped to valid blocks (holes -> 0); the caller
 masks hole scores downstream via its membership mask, exactly like the
@@ -43,7 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.stage1_int4 import unpack_plane_even_odd
+from repro.kernels.platform import resolve_interpret
+from repro.kernels.stage1_int4 import score_rows
 
 DEFAULT_BLOCK_ROWS = 64
 
@@ -51,21 +53,16 @@ DEFAULT_BLOCK_ROWS = 64
 def _stage1_gather_kernel(ids_ref, q_ref, plane_ref, out_ref):
     """ids_ref: (B, J) int32 prefetched block ids (consumed by index_maps);
     q_ref: (1, 2, D2) int8 lane query pair; plane_ref: (BR, D2) uint8 —
-    the block the index_map selected; out: (1, 1, BR)."""
+    the block the index_map selected; out: (1, 1, 1, BR)."""
     del ids_ref  # only read by the BlockSpec index_maps
-    even, odd = unpack_plane_even_odd(plane_ref[...])
-    q = q_ref[0]
-    dn = (((1,), (0,)), ((), ()))
-    s = jax.lax.dot_general(even, q[0], dn, preferred_element_type=jnp.int32)
-    s += jax.lax.dot_general(odd, q[1], dn, preferred_element_type=jnp.int32)
-    out_ref[0, 0, :] = s
+    out_ref[0, 0] = score_rows(q_ref[0], plane_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def stage1_int4_gather_pallas(q_eo: jax.Array, msb_plane: jax.Array,
                               block_ids: jax.Array, *,
                               block_rows: int = DEFAULT_BLOCK_ROWS,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: bool | None = None) -> jax.Array:
     """q_eo: (B, 2, D//2) int8 signed MSB nibble pairs (even; odd dims).
     msb_plane: (N, D//2) uint8 with N % block_rows == 0 (zero-padded).
     block_ids: (B, J) int32 ids in [0, N / block_rows) — the lane's
@@ -85,13 +82,13 @@ def stage1_int4_gather_pallas(q_eo: jax.Array, msb_plane: jax.Array,
             pl.BlockSpec((block_rows, d2),
                          lambda i, jj, ids: (ids[i, jj], 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_rows),
-                               lambda i, jj, ids: (i, 0, jj)),
+        out_specs=pl.BlockSpec((1, 1, 1, block_rows),
+                               lambda i, jj, ids: (i, jj, 0, 0)),
     )
     out = pl.pallas_call(
         _stage1_gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, j * block_rows), jnp.int32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((b, j, 1, block_rows), jnp.int32),
+        interpret=resolve_interpret(interpret),
     )(block_ids, q_eo, msb_plane)
-    return out[:, 0, :]
+    return out.reshape(b, j * block_rows)

@@ -159,8 +159,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         mesh = make_train_opt_mesh(multi_pod=(mesh_kind == "multi"))
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
-    from repro.compat import set_mesh
-    with set_mesh(mesh):                     # activates activation pins
+    with jax.set_mesh(mesh):                     # activates activation pins
         t0 = time.time()
         fn, args, in_sh, out_sh, jkw = build_step(arch, shape_name, mesh,
                                                   variant=variant)

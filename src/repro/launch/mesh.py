@@ -2,7 +2,16 @@
 module never touches jax device state)."""
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes, axis_names):
+    """jax.make_mesh with Auto axes: the sharding rules in
+    repro.distributed.sharding lean on GSPMD propagation, which Explicit
+    axes (jax.make_mesh's own default) would turn off."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,8 +3,10 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
         --num-docs 256 --requests 8 [--metric cosine] [--topk 3]
 
-Builds the offline index (MiniLM-style embedder -> INT8 nibble-planar DB,
-sharded over the mesh when --data/--model > 1), then serves batched
+Without --smoke the generator and the minilm-embedder run at their FULL
+widths from `repro.configs`. Builds the offline index (MiniLM-style
+embedder -> INT8 nibble-planar DB, sharded over the mesh when
+--data/--model > 1), then serves batched
 requests through the paper's two-stage hierarchical retrieval and the
 generator's prefill+decode, logging the Table-II-calibrated energy ledger
 per query.
@@ -20,6 +22,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.core import RetrievalConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models import embedder, get_model
 from repro.serve import RAGPipeline
@@ -28,7 +31,8 @@ from repro.serve import RAGPipeline
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen2-0.5b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced generator/embedder configs (CI, CPU)")
     ap.add_argument("--num-docs", type=int, default=256)
     ap.add_argument("--doc-len", type=int, default=12)
     ap.add_argument("--requests", type=int, default=8)
@@ -39,6 +43,7 @@ def main(argv=None):
     ap.add_argument("--model", type=int, default=1)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     gcfg = get_config(args.arch, smoke=args.smoke)
     if gcfg.family == "encdec":
@@ -47,14 +52,12 @@ def main(argv=None):
     gen_api = get_model(gcfg)
     gen_params = gen_api.init(jax.random.PRNGKey(0))
 
-    ecfg = embedder.MINILM_CFG.with_(num_layers=2, d_model=64, num_heads=4,
-                                     num_kv_heads=4, d_ff=128,
-                                     vocab_size=gcfg.vocab_size,
-                                     pooled_dim=64)
+    ecfg = get_config("minilm-embedder", smoke=args.smoke)
     eparams = embedder.init_params(ecfg, jax.random.PRNGKey(1))
 
+    vocab = min(gcfg.vocab_size, ecfg.vocab_size)   # docs feed both models
     docs = jnp.asarray(rng.integers(
-        0, gcfg.vocab_size, (args.num_docs, args.doc_len)).astype(np.int32))
+        0, vocab, (args.num_docs, args.doc_len)).astype(np.int32))
     mesh = (make_test_mesh(args.data, args.model)
             if args.data * args.model > 1 else None)
     t0 = time.time()

@@ -2,7 +2,11 @@
 
     PYTHONPATH=src python -m repro.launch.serve_tenants --tenants 8 \
         --capacity 1024 --steps 40 [--clusters 16 --cache-kb 256] \
-        [--generate] [--seed 0]
+        [--generate] [--seed 0] [--smoke]
+
+The generator (qwen2-0.5b) and the embedder (minilm-embedder, pooled to
+the paper's 512-d) come from `repro.configs` at full width; `--smoke`
+takes their reduced SMOKE configs instead.
 
 Drives the wearable deployment shape end to end: T users share one
 nibble-planar arena; every trace step either INGESTS a burst of new
@@ -30,6 +34,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import RetrievalConfig, energy, quantize_int8
 from repro.core.clustering import ClusterParams
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import embedder, get_model
 from repro.obs import (MetricsRegistry, Tracer, prometheus_text,
                        write_chrome_trace)
@@ -38,6 +43,8 @@ from repro.serve import MultiTenantRAGPipeline, RuntimeConfig, ServingRuntime
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced generator/embedder configs (CI, CPU)")
     ap.add_argument("--tenants", type=int, default=8)
     ap.add_argument("--capacity", type=int, default=1024)
     ap.add_argument("--doc-len", type=int, default=12)
@@ -137,16 +144,16 @@ def main(argv=None):
         ap.error("--fail-at injects a shard loss: it needs --shards >= 2 "
                  "(there must be a survivor to re-place onto)")
 
+    enable_compile_cache()
     rng = np.random.default_rng(args.seed)
     _maybe_autotune(args)
-    gcfg = get_config("qwen2-0.5b", smoke=True)
+    gcfg = get_config("qwen2-0.5b", smoke=args.smoke)
     gen_api = get_model(gcfg) if args.generate else None
     gen_params = gen_api.init(jax.random.PRNGKey(0)) if args.generate else None
-    ecfg = embedder.MINILM_CFG.with_(num_layers=2, d_model=64, num_heads=4,
-                                     num_kv_heads=4, d_ff=128,
-                                     vocab_size=gcfg.vocab_size,
-                                     pooled_dim=64)
+    ecfg = get_config("minilm-embedder", smoke=args.smoke)
     eparams = embedder.init_params(ecfg, jax.random.PRNGKey(1))
+    # doc tokens feed both models
+    vocab = min(gcfg.vocab_size, ecfg.vocab_size)
 
     pipe = MultiTenantRAGPipeline.create(
         ecfg, eparams, gen_api, gen_params, capacity=args.capacity,
@@ -180,7 +187,7 @@ def main(argv=None):
         event = rng.choice(["ingest", "ingest", "query", "query", "delete"])
         tenant = int(rng.integers(args.tenants))
         if event == "ingest" or not docs_of[tenant]:
-            toks = rng.integers(0, gcfg.vocab_size,
+            toks = rng.integers(0, vocab,
                                 (args.burst, args.doc_len)).astype(np.int32)
             if pipe.index.arena.num_free < args.burst:
                 pipe.compact()
@@ -293,7 +300,8 @@ def main(argv=None):
           f"{dense_b:,} dense, {dense_b / max(dbytes, 1):.1f}x cut)")
     if args.arrival != "closed":
         _openloop_phase(args, pipe, runtime, docs_of, rng)
-    sharded_ok = _sharded_phase(args, rng) if args.shards else True
+    sharded_ok = (_sharded_phase(args, rng, ecfg.pooled_dim) if args.shards
+                  else True)
     _obs_report(args, registry, tracer)
 
     if args.generate and queries:
@@ -306,7 +314,7 @@ def main(argv=None):
     return 1 if (leaks or not sharded_ok) else 0
 
 
-def _sharded_phase(args, rng) -> bool:
+def _sharded_phase(args, rng, dim: int) -> bool:
     """--shards: pod-scale sharded serving over the elastic failover path.
 
     A synthetic per-tenant INT8 corpus (codes are what the placement
@@ -321,7 +329,7 @@ def _sharded_phase(args, rng) -> bool:
     from repro.core.retrieval import RetrievalConfig
     from repro.serve.sharded import (ShardedRuntimeConfig,
                                      ShardedServingRuntime)
-    tenants, dpt, dim = args.tenants, max(args.burst, 8), 64
+    tenants, dpt = args.tenants, max(args.burst, 8)
     docs = {t: rng.integers(-40, 41, (dpt, dim), dtype=np.int8)
             for t in range(tenants)}
     trace = [(t, rng.integers(-40, 41, (dim,), dtype=np.int8))

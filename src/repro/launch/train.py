@@ -21,6 +21,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_IDS, get_config
 from repro.data import LMTaskConfig, lm_batches
 from repro.distributed import compression, sharding as sh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.runtime import ElasticTrainer
 from repro.train import get_optimizer, make_train_step
@@ -42,6 +43,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--save-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     api = get_model(cfg)
@@ -72,8 +74,7 @@ def main(argv=None):
         jitted = jax.jit(raw)
 
         def step_fn(p, o, b, mesh):
-            from repro.compat import set_mesh
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 return jitted(p, o, b)
 
         return params, opt_state, step_fn, (pspec, ospec)

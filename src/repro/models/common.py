@@ -199,9 +199,8 @@ def constrain(x: jax.Array, *pattern: str | None) -> jax.Array:
     """
     from jax.sharding import PartitionSpec  # local: avoid cycles
 
-    from repro.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = mesh.axis_names
     mp = "model" if "model" in names else None
@@ -232,9 +231,8 @@ def constrain_kv(kc: jax.Array) -> jax.Array:
     T->mp (context-parallel decode)."""
     from jax.sharding import PartitionSpec
 
-    from repro.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty or kc.ndim != 4:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or kc.ndim != 4:
         return kc
     names = mesh.axis_names
     mp = "model" if "model" in names else None

@@ -307,7 +307,7 @@ def decode_step_quant(params: Params, cache: QuantCache, tokens: jax.Array,
                       cfg: ModelConfig, top_k: int = 256,
                       npages: int | None = None,
                       prescreen_c0: int | None = None,
-                      backend: str = "jnp"
+                      backend: str | None = None
                       ) -> tuple[jax.Array, QuantCache]:
     """Decode against the INT8 nibble-planar K cache via the engine's KV
     cascade. Per step per layer, HBM reads are the MSB plane (T*hd/2 B)

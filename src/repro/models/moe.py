@@ -55,9 +55,8 @@ def init_moe_ffn(cfg: ModelConfig, key) -> Params:
 
 def _dp_shards() -> int:
     """Number of batch-axis shards in the ambient mesh (1 outside set_mesh)."""
-    from repro.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return 1
     n = 1
     for a in mesh.axis_names:

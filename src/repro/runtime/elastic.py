@@ -26,8 +26,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.compat import mesh_from_device_array
-
 from repro.checkpoint import CheckpointManager
 from repro.runtime.fault import HeartbeatMonitor, StragglerDetector
 
@@ -59,7 +57,7 @@ def build_mesh_from(devices: Sequence, model_parallel: int) -> Mesh:
         mp //= 2
     dp = n // mp
     devs = np.asarray(devices[:dp * mp]).reshape(dp, mp)
-    return mesh_from_device_array(devs, ("data", "model"))
+    return Mesh(devs, ("data", "model"))
 
 
 @dataclasses.dataclass

@@ -165,7 +165,7 @@ class RAGAgent:
     npages: int | None = None
     prescreen_c0: int | None = None
     page_rows: int = 8
-    backend: str = "jnp"
+    backend: str | None = None      # None: Pallas on a TPU, jnp elsewhere
     _decode_jit: Any = dataclasses.field(default=None, repr=False,
                                          compare=False)
 

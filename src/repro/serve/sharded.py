@@ -186,9 +186,12 @@ class ShardedServingRuntime:
         for sid in range(c.num_shards):
             dev = devices[sid % len(devices)]
             with jax.default_device(dev):
+                # The arena is COMMITTED to the shard's device, so later
+                # ingests, failover re-ingests and launches run there even
+                # outside this default-device context.
                 index = MultiTenantIndex(
                     c.capacity_per_shard, c.dim, c.retrieval,
-                    scale=c.scale, clusters=c.clusters)
+                    scale=c.scale, clusters=c.clusters, device=dev)
                 runtime = ServingRuntime(
                     index, c.runtime,
                     registry=self.registry.labeled(shard=str(sid)))

@@ -176,7 +176,7 @@ def sparse_decode_attention(q: jax.Array, cache: QuantKVCache,
                             npages: int | None = None,
                             prescreen_c0: int | None = None,
                             page_rows: int = 8,
-                            backend: str = "jnp") -> jax.Array:
+                            backend: str | None = None) -> jax.Array:
     """q (B, 1, H, hd) against the quantized cache; returns (B, 1, H, hd).
 
     Dispatches into the engine's KV cascade. The default (no npages /
@@ -185,7 +185,8 @@ def sparse_decode_attention(q: jax.Array, cache: QuantKVCache,
     and is bit-identical to `sparse_decode_attention_ref`. `npages`
     prepends the Quest-style page prune (needs cent_msb on the cache);
     `prescreen_c0` adds the 1-bit sign prescreen between prune and scan;
-    `backend` selects jnp vs Pallas kernels for the integer stages.
+    `backend` selects jnp vs Pallas kernels for the integer stages (None:
+    the kernels on a TPU, jnp elsewhere).
     """
     cfg = engine.KVCascadeConfig(
         top_k=top_k, npages=npages, page_rows=page_rows,

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -50,7 +51,11 @@ class ArenaStats:
 class Arena:
     """One shared slab serving many tenants' rows side by side."""
 
-    def __init__(self, capacity: int, dim: int, *, scale: float | None = None):
+    def __init__(self, capacity: int, dim: int, *, scale: float | None = None,
+                 device=None):
+        """device: commit the planes to this jax device (a serving shard's
+        own chip); every later update and launch then stays on it. None
+        leaves them uncommitted on the default device."""
         if dim % 2:
             raise ValueError("dim must be even for nibble-planar packing")
         self.capacity = capacity
@@ -66,6 +71,11 @@ class Arena:
                            if dim % 8 == 0 else None)
         self.norms_sq = jnp.zeros((capacity,), jnp.int32)
         self.owner = jnp.full((capacity,), FREE, jnp.int32)
+        if device is not None:
+            (self.msb_plane, self.lsb_plane, self.sign_plane, self.norms_sq,
+             self.owner) = jax.device_put(
+                (self.msb_plane, self.lsb_plane, self.sign_plane,
+                 self.norms_sq, self.owner), device)
         # slot -> cluster label (host-side; -1 = unassigned/free). The
         # arena is clustering-agnostic storage: labels are written by the
         # index layer (repro.core.clustering assigns them) and kept in

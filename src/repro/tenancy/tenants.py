@@ -113,8 +113,9 @@ class MultiTenantIndex:
     def __init__(self, capacity: int, dim: int,
                  cfg: retrieval.RetrievalConfig | None = None,
                  *, scale: float | None = None,
-                 clusters: clustering.ClusterParams | None = None):
-        self.arena = Arena(capacity, dim, scale=scale)
+                 clusters: clustering.ClusterParams | None = None,
+                 device=None):
+        self.arena = Arena(capacity, dim, scale=scale, device=device)
         self.table = TenantTable()
         self.cfg = cfg or retrieval.RetrievalConfig()
         self._engine = engine.RetrievalEngine(self.cfg)

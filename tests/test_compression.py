@@ -56,15 +56,16 @@ def test_compress_preserves_large_values(seed):
 def test_two_level_all_reduce_single_device_mesh():
     """On a (pod=1, data=1) mesh the two-level reduction must be exact
     identity-mean (numerics of the quantize/dequantize path)."""
-    from repro.compat import make_mesh, shard_map
-    mesh = make_mesh((1, 1), ("pod", "data"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1, 1), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     reduce_fn = comp.make_two_level_all_reduce(mesh)
     g = {"w": jax.random.normal(jax.random.PRNGKey(0), (33,))}
 
-    out = shard_map(lambda t: reduce_fn(t), mesh=mesh,
-                    in_specs=jax.sharding.PartitionSpec(),
-                    out_specs=jax.sharding.PartitionSpec(),
-                    check_vma=False)(g)
+    out = jax.shard_map(lambda t: reduce_fn(t), mesh=mesh,
+                        in_specs=jax.sharding.PartitionSpec(),
+                        out_specs=jax.sharding.PartitionSpec(),
+                        check_vma=False)(g)
     scale = float(jnp.max(jnp.abs(g["w"]))) / 127.0
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                atol=scale * 0.5 + 1e-6)

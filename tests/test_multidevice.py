@@ -23,14 +23,14 @@ def run_sub(code: str, timeout=600):
 def test_sharded_index_tournament_equals_single_shard():
     run_sub("""
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from repro.core import *
 from repro.core.index import ShardedIndex
 from repro.core.retrieval import RetrievalConfig, two_stage_retrieve
 from repro.core.bitplanar import BitPlanarDB
 rng = np.random.default_rng(1)
 emb = jnp.asarray(rng.normal(size=(1000, 512)).astype(np.float32))
-mesh = make_mesh((4, 2), ('data', 'model'))
+mesh = jax.make_mesh((4, 2), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)
 idx = ShardedIndex.build(emb, mesh)
 db = build_database(emb); bp = BitPlanarDB.from_quantized(db)
 for metric in ['cosine', 'mips']:
@@ -48,12 +48,12 @@ print('OK')
 def test_sharded_train_step_all_families():
     run_sub("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh, set_mesh
+from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.models import get_model
 from repro.train import get_optimizer, make_train_step
 from repro.distributed import sharding as sh
-mesh = make_mesh((4, 2), ('data', 'model'))
+mesh = jax.make_mesh((4, 2), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)
 for aid in ['minitron-4b', 'llama4-maverick-400b-a17b', 'zamba2-2.7b',
             'internvl2-26b', 'seamless-m4t-medium']:
     cfg = get_config(aid, smoke=True)
@@ -74,7 +74,7 @@ for aid in ['minitron-4b', 'llama4-maverick-400b-a17b', 'zamba2-2.7b',
         batch['prefix_embeds'] = jnp.zeros((8, cfg.num_prefix_embeds, cfg.d_model), jnp.float32)
     batch = jax.device_put(batch, sh.batch_shardings(jax.eval_shape(lambda: batch), mesh))
     step = make_train_step(api.loss_fn, opt)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         p2, o2, m = jax.jit(step)(params, opt_state, batch)
     loss = float(m['loss'])
     assert loss == loss, aid   # not NaN
@@ -88,14 +88,14 @@ def test_two_level_compressed_all_reduce_multidevice():
     run_sub("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.distributed import compression as comp
-mesh = make_mesh((2, 4), ('pod', 'data'))
+mesh = jax.make_mesh((2, 4), ('pod', 'data'), axis_types=(AxisType.Auto,) * 2)
 reduce_fn = comp.make_two_level_all_reduce(mesh)
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 33))
-out = shard_map(lambda t: reduce_fn({'w': t})['w'], mesh=mesh,
-                in_specs=P(('pod', 'data')), out_specs=P(('pod', 'data')),
-                check_vma=False)(g)
+out = jax.shard_map(lambda t: reduce_fn({'w': t})['w'], mesh=mesh,
+                    in_specs=P(('pod', 'data')), out_specs=P(('pod', 'data')),
+                    check_vma=False)(g)
 want = jnp.broadcast_to(jnp.mean(g, axis=0, keepdims=True), g.shape)
 err = float(jnp.max(jnp.abs(out - want)))
 scale = float(jnp.max(jnp.abs(g))) / 127.0

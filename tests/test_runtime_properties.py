@@ -15,6 +15,9 @@ The index is built with fragmented tenants so the batched path runs the
 full-arena masked scan, whose per-lane results are independent of batch
 composition by construction — making the sequential reference exact.
 """
+from unittest import mock
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -24,7 +27,8 @@ pytest.importorskip(
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import RetrievalConfig, quantize_int8  # noqa: E402
-from repro.serve.runtime import RuntimeConfig, ServingRuntime  # noqa: E402
+from repro.serve.runtime import (  # noqa: E402
+    RuntimeConfig, ServingRuntime, _InFlight)
 from repro.tenancy import MultiTenantIndex  # noqa: E402
 
 DIM = 32
@@ -137,8 +141,16 @@ def test_trace_completeness_under_random_schedules(schedule, max_batch,
     are exactly the submitted request ids. Runs under the simulated
     clock, so the whole trace (timestamps included) must be
     deterministic: replaying the schedule yields a bit-identical event
-    list."""
+    list. poll() retires a launch once its device buffers have landed,
+    which depends on how fast the device ran; the readiness probe is
+    made to wait for the buffers, so every reap retires what was
+    dispatched and the order of resolves is a function of the schedule
+    alone."""
     from repro.obs import MetricsRegistry, Tracer
+
+    def landed(infl):
+        jax.block_until_ready(infl.res)
+        return True
 
     def drive():
         reg, tracer = MetricsRegistry(), Tracer()
@@ -147,16 +159,17 @@ def test_trace_completeness_under_random_schedules(schedule, max_batch,
             auto_flush=False), registry=reg, tracer=tracer)
         now = 0.0
         submitted = []
-        for op, a, b, c in schedule:
-            if op == "submit":
-                submitted.append(rt.submit(a, _POOL[a][b], now=now,
-                                           deadline=now + c))
-            elif op == "poll":
-                now += a
-                rt.poll(now=now)
-            else:
-                rt.flush()
-        rt.flush()
+        with mock.patch.object(_InFlight, "is_ready", landed):
+            for op, a, b, c in schedule:
+                if op == "submit":
+                    submitted.append(rt.submit(a, _POOL[a][b], now=now,
+                                               deadline=now + c))
+                elif op == "poll":
+                    now += a
+                    rt.poll(now=now)
+                else:
+                    rt.flush()
+            rt.flush()
         return reg, tracer, submitted
 
     reg, tracer, submitted = drive()

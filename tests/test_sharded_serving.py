@@ -11,6 +11,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -107,7 +108,7 @@ def test_tournament_pad_rows_masked_for_all_negative_corpus():
     """pad_database appends zero docs (score 0). With an all-negative
     MIPS corpus, 0 beats every real doc — pre-fix the tournament returned
     the pad ids (>= n_global); the fix masks them out of both stages."""
-    from repro.compat import make_mesh
+    from jax.sharding import AxisType
     from repro.core import quantization
     from repro.core.bitplanar import BitPlanarDB
     from repro.core.index import ShardedIndex, pad_database, shard_database
@@ -119,7 +120,7 @@ def test_tournament_pad_rows_masked_for_all_negative_corpus():
     db = quantization.build_database(jnp.asarray(emb))
     bp = BitPlanarDB.from_quantized(db)
     n_global = bp.num_docs
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     idx = ShardedIndex(db=shard_database(pad_database(bp, 4), mesh),
                        mesh=mesh, n_global=n_global)   # 2 pad rows
     qc = np.asarray(quantization.quantize_int8_fixed(jnp.asarray(q),
