@@ -984,7 +984,10 @@ def _run_cascade(query_codes: jax.Array, db: bitplanar.BitPlanarDB,
                       fns=stage_fns(cfg.backend), q_sign=q_sign)
     state = _CascadeState()
     for stage in cascade_stages(policy, cfg):
-        state = stage.run(state, ctx)
+        # named scopes are metadata: they name the stage's ops in the
+        # compiled program and its profile, and change no computation
+        with jax.named_scope("cascade." + type(stage).__name__):
+            state = stage.run(state, ctx)
     return state
 
 
@@ -1641,14 +1644,17 @@ def _kv_run(q: jax.Array, policy: KVCachePolicy,
         # Integer query operands for the kernel stages: per-head-vector
         # INT8 quantization (a per-lane positive scale — re-applied to the
         # centroid key before group-max so heads compare on equal terms).
-        q_codes, q_scale = quantization.quantize_int8(
-            qg.reshape(b * kh * g, hd), per_vector=True)
+        with jax.named_scope("kv.QueryQuant"):
+            q_codes, q_scale = quantization.quantize_int8(
+                qg.reshape(b * kh * g, hd), per_vector=True)
     ctx = _KVCtx(q=q, qg=qg, policy=policy, cfg=cfg,
                  fns=stage_fns(cfg.backend), q_codes=q_codes,
                  q_scale=q_scale)
     state = _KVState()
     for stage in kv_cascade_stages(cfg):
-        state = stage.run(state, ctx)
+        # metadata only, as in _run_cascade
+        with jax.named_scope("kv." + type(stage).__name__):
+            state = stage.run(state, ctx)
     return state
 
 

@@ -38,6 +38,7 @@ _COMP_HDR = re.compile(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s*\(")
 _TRIP_CFG = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
 _OP_LINE = re.compile(r"^\s*(?:ROOT\s+)?%([\w\.\-]+)\s*=\s*(.+)$")
 _SHAPE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 _TUPLE_SHAPE = re.compile(r"^\(")
 
 
@@ -160,6 +161,26 @@ def _dot_flops(line: str, comp: _Comp, rhs: str) -> float:
     for d in rdims:
         nres *= d
     return 2.0 * nres * k
+
+
+def op_scopes(text: str, prefixes=("kv.", "cascade.")) -> dict[str, str]:
+    """Instruction name -> its innermost named scope (`jax.named_scope`)
+    whose name starts with one of `prefixes`, read from the `op_name`
+    metadata of compiled HLO text (`compiled.as_text()`). Instructions in
+    no such scope are left out. A fusion carries the metadata of its
+    root, so it takes the scope of its root even where it fused ops of
+    another scope in."""
+    out: dict[str, str] = {}
+    for line in text.splitlines():
+        m = _OP_LINE.match(line)
+        md = m and _OP_NAME.search(m.group(2))
+        if not md:
+            continue
+        for part in reversed(md.group(1).split("/")):
+            if part.startswith(tuple(prefixes)):
+                out[m.group(1)] = part
+                break
+    return out
 
 
 def analyze(text: str, top_ops: int = 0) -> dict:

@@ -1,16 +1,12 @@
-"""Exporters: Prometheus text format, JSON-lines, Chrome trace_event.
+"""Exporters: Prometheus text format, Chrome trace_event.
 
-Three render targets for one run's registry + tracer:
+Two render targets for one run's registry + tracer:
 
   * `prometheus_text(registry)` — the Prometheus text exposition format
     (counters/gauges verbatim, histograms as cumulative ``_bucket{le=}``
     series plus ``_sum``/``_count``), scrape-ready. A minimal validating
     `parse_prometheus` lives here too so CI can assert the export stays
     well-formed without a prometheus client dependency.
-  * `write_jsonl(path, registry, tracer)` — one JSON object per line:
-    every metric as a ``{"type": "metric", ...}`` record, every trace
-    event as ``{"type": "event", ...}`` — the grep/jq-friendly event
-    log.
   * `chrome_trace(tracer)` / `write_chrome_trace(path, tracer)` — the
     Chrome ``trace_event`` JSON array format. Open the file in Perfetto
     (https://ui.perfetto.dev) or chrome://tracing to see every request's
@@ -126,47 +122,6 @@ def parse_prometheus(text: str) -> dict[str, list]:
         value = float("inf") if val == "+Inf" else float(val)
         out.setdefault(m.group("name"), []).append((labels, value))
     return out
-
-
-def metrics_jsonl_records(registry) -> list[dict]:
-    records = []
-    for kind, m in registry.metrics():
-        rec = {"type": "metric", "kind": kind, "name": m.name,
-               "labels": dict(m.labels)}
-        if kind == "histogram":
-            rec.update(m.summary())
-        else:
-            rec["value"] = m.value
-        records.append(rec)
-    return records
-
-
-def trace_jsonl_records(tracer) -> list[dict]:
-    records = []
-    for e in tracer.spans():
-        rec = {"type": "event", "name": e.name, "ph": e.ph, "ts": e.ts,
-               "tid": e.tid}
-        if e.dur is not None:
-            rec["dur"] = e.dur
-        if e.attrs:
-            rec["attrs"] = e.attrs
-        records.append(rec)
-    return records
-
-
-def write_jsonl(path: str, registry=None, tracer=None) -> int:
-    """Write the metrics snapshot and/or trace events as JSON lines.
-
-    Returns the number of records written."""
-    records = []
-    if registry is not None:
-        records += metrics_jsonl_records(registry)
-    if tracer is not None:
-        records += trace_jsonl_records(tracer)
-    with open(path, "w") as f:
-        for rec in records:
-            f.write(json.dumps(rec, sort_keys=True) + "\n")
-    return len(records)
 
 
 def chrome_trace(tracer, *, pid: int = 0) -> dict:

@@ -11,24 +11,36 @@ trace every time.
 Two span styles:
 
   * ``with tracer.span("flush", now=...):`` — a synchronous phase; emits
-    one COMPLETE event (begin + duration) when the block exits.
+    one COMPLETE event (begin + duration) when the block exits, and
+    enters a `jax.profiler.TraceAnnotation` of the same name for its
+    extent.
   * ``tracer.begin(name, key, now)`` / ``tracer.end(key, now)`` — an
     ASYNC lifecycle that outlives any one call frame (a request between
     submit and resolve). Keys must be unique among open spans: a double
     begin or an end without a begin raises immediately instead of
     silently producing an unbalanced trace.
 
-Events are plain host-side records (`TraceEvent`); exporters in
-repro.obs.export render them as JSON-lines or Chrome ``trace_event``
-JSON (openable in Perfetto / chrome://tracing). Like the metrics
-registry, tracing is host-side only — never inside jitted code — and
-`NullTracer` (`NULL_TRACER`) makes every call a no-op when disabled.
+Events are plain host-side records (`TraceEvent`); repro.obs.export
+renders them as Chrome ``trace_event`` JSON (openable in Perfetto /
+chrome://tracing).
+
+Spans also reach the JAX profiler: while one records, each `span` lies
+on its host plane beside the device's ops, on the device trace's clock,
+so an idle stretch of the device can be named by the host phase around
+it. With no profiler recording an annotation costs one TraceMe check
+(under a microsecond). `NullTracer` (`NULL_TRACER`) records nothing in
+memory but still annotates its spans, so a runtime built without a
+tracer is profiled all the same. Spans are recorded on the host, around
+dispatches; inside jitted code the program names its parts with
+`jax.named_scope`, which is metadata and changes no computation.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -93,14 +105,16 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, *, now: float | None = None,
              tid: int | str = 0, **attrs):
-        """Synchronous phase: one complete ("X") event on exit.
+        """Synchronous phase: one complete ("X") event on exit, and a
+        profiler annotation named `name` for the block's extent.
 
         With an explicit `now` the duration is 0 in simulated time
         (deterministic); without one, start/end are read from the
         tracer's clock."""
         t0 = self._now(now)
         try:
-            yield self
+            with TraceAnnotation(name):
+                yield self
         finally:
             t1 = t0 if now is not None else self._now(None)
             self.events.append(TraceEvent(name=name, ph="X", ts=t0,
@@ -126,7 +140,8 @@ class Tracer:
 
 
 class NullTracer:
-    """Tracing switched off: every call a no-op, `span` an empty context."""
+    """In-memory tracing switched off: every call records nothing; `span`
+    is the profiler annotation alone."""
 
     enabled = False
     events: list = []
@@ -140,9 +155,8 @@ class NullTracer:
     def end(self, key, *, now=None, **attrs):
         pass
 
-    @contextlib.contextmanager
     def span(self, name, *, now=None, tid=0, **attrs):
-        yield self
+        return TraceAnnotation(name)
 
     def open_spans(self):
         return []

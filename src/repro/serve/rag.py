@@ -187,6 +187,8 @@ class RAGAgent:
             knobs = dict(top_k=self.top_k, npages=self.npages,
                          prescreen_c0=self.prescreen_c0,
                          backend=self.backend)
+            # its program's XLA name, `jit__lambda`, is how the benchmark
+            # finds the decode step in a trace (bench/layer.py)
             self._decode_jit = jax.jit(
                 lambda p, c, t: dense.decode_step_quant(p, c, t, cfg,
                                                         **knobs))
@@ -204,63 +206,76 @@ class RAGAgent:
              max_new: int = 16, temperature: float = 0.0, key=None,
              now: float | None = None) -> AgentTurnReport:
         """Retrieve through the runtime, generate with the KV cascade,
-        charge both against one registry. Returns an AgentTurnReport."""
+        charge both against one registry. Returns an AgentTurnReport.
+
+        Each phase is a span of the runtime's tracer (`rag.retrieve`,
+        `rag.prefill`, `rag.decode`, `rag.account`), so a profiler
+        places the turn's host work beside the device's."""
         from repro.models import dense
 
         pipe = self.pipeline
         api, cfg = pipe.gen_api, pipe.gen_api.cfg
+        tracer = self.runtime.tracer
         # 1. retrieval: per-request admission through the runtime (the
         # scheduler batches the tenants into one segment-masked launch).
-        q_emb = pipe._embed(jnp.asarray(query_tokens))
-        q_codes, _ = quantize_int8(q_emb, per_vector=True)
-        codes = np.asarray(q_codes)
-        handles = [self.runtime.submit(int(t), codes[i], now=now)
-                   for i, t in enumerate(np.asarray(tenant_ids))]
-        self.runtime.flush(now=now)
-        ids = np.stack([np.asarray(h.result().indices) for h in handles])
-        retrieval_cost = self.runtime.energy_ledger(q_emb.shape[-1])
-        # 2. prompt assembly (invalid hits contribute zero tokens).
-        b, k = ids.shape
-        flat = ids.reshape(-1)
-        docs = np.where((flat >= 0)[:, None],
-                        pipe.doc_tokens[np.maximum(flat, 0)], 0)
-        docs = jnp.asarray(docs.reshape(b, k * pipe.doc_tokens.shape[1]))
-        prompt = jnp.concatenate([docs, jnp.asarray(query_tokens)], axis=1)
-        prompt = jnp.clip(prompt, 0, cfg.vocab_size - 1)
-        # 3. prefill (cached jit — no per-turn recompiles), then convert
-        # the bf16 cache to the nibble-planar QuantCache once.
-        total = self._total_len(prompt.shape[1], max_new)
-        prefill_fn, _ = jitted_fns(api)
-        logits, cache = prefill_fn(self.pipeline.gen_params,
-                                   {"tokens": prompt}, max_len=total)
-        qcache = dense.quantize_cache(
-            cache, page_rows=self.page_rows if self.npages else None)
+        with tracer.span("rag.retrieve", now=now):
+            q_emb = pipe._embed(jnp.asarray(query_tokens))
+            q_codes, _ = quantize_int8(q_emb, per_vector=True)
+            codes = np.asarray(q_codes)
+            handles = [self.runtime.submit(int(t), codes[i], now=now)
+                       for i, t in enumerate(np.asarray(tenant_ids))]
+            self.runtime.flush(now=now)
+            ids = np.stack([np.asarray(h.result().indices)
+                            for h in handles])
+            retrieval_cost = self.runtime.energy_ledger(q_emb.shape[-1])
+        with tracer.span("rag.prefill", now=now):
+            # 2. prompt assembly (invalid hits contribute zero tokens).
+            b, k = ids.shape
+            flat = ids.reshape(-1)
+            docs = np.where((flat >= 0)[:, None],
+                            pipe.doc_tokens[np.maximum(flat, 0)], 0)
+            docs = jnp.asarray(docs.reshape(b, k * pipe.doc_tokens.shape[1]))
+            prompt = jnp.concatenate([docs, jnp.asarray(query_tokens)],
+                                     axis=1)
+            prompt = jnp.clip(prompt, 0, cfg.vocab_size - 1)
+            # 3. prefill (cached jit — no per-turn recompiles), then
+            # convert the bf16 cache to the nibble-planar QuantCache once.
+            total = self._total_len(prompt.shape[1], max_new)
+            prefill_fn, _ = jitted_fns(api)
+            logits, cache = prefill_fn(self.pipeline.gen_params,
+                                       {"tokens": prompt}, max_len=total)
+            qcache = dense.quantize_cache(
+                cache, page_rows=self.page_rows if self.npages else None)
         # 4. decode loop: every step's attention is the engine cascade.
-        key = key if key is not None else jax.random.PRNGKey(0)
-        step = self._decode_step()
-        tok = sample_tokens(logits[:, -1:], key, temperature)
-        outs = [tok]
-        for i in range(max_new - 1):
-            key = jax.random.fold_in(key, i)
-            logits, qcache = step(pipe.gen_params, qcache, tok)
-            tok = sample_tokens(logits, key, temperature)
-            outs.append(tok)
-        toks = jnp.concatenate(outs, axis=1)
+        with tracer.span("rag.decode", now=now):
+            key = key if key is not None else jax.random.PRNGKey(0)
+            step = self._decode_step()
+            tok = sample_tokens(logits[:, -1:], key, temperature)
+            outs = [tok]
+            for i in range(max_new - 1):
+                key = jax.random.fold_in(key, i)
+                logits, qcache = step(pipe.gen_params, qcache, tok)
+                tok = sample_tokens(logits, key, temperature)
+                outs.append(tok)
+            toks = jnp.concatenate(outs, axis=1)
         # 5. decode accounting: one kv_plan prices the run (the stage
         # geometry is fixed at the cache's allocated length), charged
         # through the SAME runtime as the retrieval launch.
-        kv_cfg = engine_mod.KVCascadeConfig(
-            top_k=self.top_k, npages=self.npages, page_rows=self.page_rows,
-            prescreen_c0=self.prescreen_c0, backend=self.backend)
-        plan = engine_mod.kv_plan(kv_cfg, batch=b,
-                                  kv_heads=cfg.num_kv_heads,
-                                  q_heads=cfg.num_heads, seq_len=total,
-                                  head_dim=cfg.hd, layers=cfg.num_layers)
-        decode_cost = self.runtime.account_decode(plan, dim=cfg.hd,
-                                                  tokens=max_new)
-        from repro.serve import sparse_kv
-        dense_bytes = (b * cfg.num_layers * cfg.num_kv_heads
-                       * sparse_kv.dense_bytes_per_step(total, cfg.hd))
+        with tracer.span("rag.account", now=now):
+            kv_cfg = engine_mod.KVCascadeConfig(
+                top_k=self.top_k, npages=self.npages,
+                page_rows=self.page_rows, prescreen_c0=self.prescreen_c0,
+                backend=self.backend)
+            plan = engine_mod.kv_plan(kv_cfg, batch=b,
+                                      kv_heads=cfg.num_kv_heads,
+                                      q_heads=cfg.num_heads, seq_len=total,
+                                      head_dim=cfg.hd,
+                                      layers=cfg.num_layers)
+            decode_cost = self.runtime.account_decode(plan, dim=cfg.hd,
+                                                      tokens=max_new)
+            from repro.serve import sparse_kv
+            dense_bytes = (b * cfg.num_layers * cfg.num_kv_heads
+                           * sparse_kv.dense_bytes_per_step(total, cfg.hd))
         return AgentTurnReport(
             tokens=toks, retrieved=ids, retrieval_cost=retrieval_cost,
             decode_cost=decode_cost, decode_plan=plan,
@@ -310,7 +325,10 @@ class MultiTenantRAGPipeline:
     def _embed(self, tokens: jax.Array) -> jax.Array:
         if self._encode is None:
             cfg = self.emb_cfg
-            self._encode = jax.jit(lambda p, t: emb_mod.encode(p, t, cfg))
+
+            def encode_tokens(p, t):
+                return emb_mod.encode(p, t, cfg)
+            self._encode = jax.jit(encode_tokens)
         return self._encode(self.emb_params, tokens)
 
     # -- online corpus mutation -------------------------------------------

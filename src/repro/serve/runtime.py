@@ -1297,35 +1297,42 @@ class ServingRuntime:
             if infl is target:
                 return
 
+    def _phase(self, name: str):
+        """A span over one host phase of a launch, stamped with the
+        runtime's last `now` like the launch span (deterministic under a
+        simulated clock); its extent shows on the profiler's clock."""
+        return self.tracer.span(name, now=self._last_now)
+
     def _retire(self, infl: _InFlight) -> None:
         """Resolve one launch: materialize the batch's device arrays
         (blocking if still executing), hand out numpy row views, close
         request spans, then run the launch's deferred bookkeeping —
         always in dispatch order, so the cache/ledger mutation sequence
         is the synchronous path's sequence."""
-        res = infl.res
-        # Materialize the batch ONCE and hand out numpy row views:
-        # slicing jnp arrays per lane would dispatch 3 eager device ops
-        # per request (a measurable per-flush tax at serving batch sizes).
-        indices = np.asarray(res.indices)
-        scores = np.asarray(res.scores)
-        cands = np.asarray(res.candidate_indices)
-        self._m_resolve_lag.observe(
-            max(0.0, time.monotonic() - infl.dispatch_t))
-        for i, req in enumerate(infl.group):
-            req.handle._result = RetrievalResult(
-                indices=indices[i], scores=scores[i],
-                candidate_indices=cands[i])
-            req.handle._inflight = None
-            self._m_queue_wait.observe(
-                max(0.0, infl.admit_now - req.submit_ts))
-            self.tracer.end(req.handle.request_id, now=infl.admit_now,
-                            request=req.handle.request_id,
-                            launch=infl.launch_index)
-        self._m_resolved.inc(len(infl.group))
-        if infl.book is not None:
-            infl.book()
-        self._m_inflight.set(float(len(self._inflight)))
+        with self._phase("runtime.retire"):
+            res = infl.res
+            # Materialize the batch ONCE and hand out numpy row views:
+            # slicing jnp arrays per lane would dispatch 3 eager device ops
+            # per request (a measurable per-flush tax at serving batch sizes).
+            indices = np.asarray(res.indices)
+            scores = np.asarray(res.scores)
+            cands = np.asarray(res.candidate_indices)
+            self._m_resolve_lag.observe(
+                max(0.0, time.monotonic() - infl.dispatch_t))
+            for i, req in enumerate(infl.group):
+                req.handle._result = RetrievalResult(
+                    indices=indices[i], scores=scores[i],
+                    candidate_indices=cands[i])
+                req.handle._inflight = None
+                self._m_queue_wait.observe(
+                    max(0.0, infl.admit_now - req.submit_ts))
+                self.tracer.end(req.handle.request_id, now=infl.admit_now,
+                                request=req.handle.request_id,
+                                launch=infl.launch_index)
+            self._m_resolved.inc(len(infl.group))
+            if infl.book is not None:
+                infl.book()
+            self._m_inflight.set(float(len(self._inflight)))
 
     def _form_batch(self) -> list[_Pending]:
         """Pick up to max_batch pending requests.
@@ -1409,8 +1416,8 @@ class ServingRuntime:
                                 request=req.handle.request_id,
                                 launch=self.launches)
         t0 = time.monotonic()
-        with self.tracer.span("launch", now=now, batch=b, padded=pb,
-                              index=self.launches):
+        with self.tracer.span("runtime.launch", now=now, batch=b,
+                              padded=pb, index=self.launches):
             res, plan, book = self._execute(queries, tids)
         # Dispatch wall only — execution overlaps the host from here on;
         # serve_resolve_lag_seconds (observed at retire) is the other half.
@@ -1636,20 +1643,24 @@ class ServingRuntime:
                           policy.labels, k_clusters)
         compact = False
         prefetched = 0
-        if self.cfg.preload:
-            prefetched, compact = self._preload_tenants(tids)
-        if not compact:
-            prefetched += self._warm_from_prior(tids)
+        with self._phase("runtime.preload"):
+            if self.cfg.preload:
+                prefetched, compact = self._preload_tenants(tids)
+            if not compact:
+                prefetched += self._warm_from_prior(tids)
         # ONE fill dispatch per launch: the previous launch's deferred
         # miss admissions plus this launch's warming, applied before the
         # indirection table can reference their slots.
-        cache.flush_fills()
-        if compact:
-            slab_blocks, width = cache.compact_table(tids, k_clusters)
-            if min(policy.nprobe, k_clusters) * width * br < index.cfg.k:
-                compact = False     # view too narrow to hold k: full width
-        if not compact:
-            slab_blocks = cache.combined_table(tids, host_table)
+        with self._phase("runtime.fill"):
+            cache.flush_fills()
+        with self._phase("runtime.tables"):
+            if compact:
+                slab_blocks, width = cache.compact_table(tids, k_clusters)
+                if min(policy.nprobe, k_clusters) * width * br < index.cfg.k:
+                    compact = False   # view too narrow to hold k: full width
+            if not compact:
+                slab_blocks = cache.combined_table(tids, host_table)
+            cluster_valid = self._cluster_valid(tids, host_table)
         # Stage-0 prescreen operand: the combined sign plane (derived
         # from the combined msb plane, cached per plane state) rides
         # along whenever the config prescreens — resident clusters'
@@ -1660,7 +1671,7 @@ class ServingRuntime:
             packed_labels=cache.packed_labels,
             tenant_ids=policy.tenant_ids, centroid_msb=policy.centroid_msb,
             centroid_norms=policy.centroid_norms,
-            cluster_valid=self._cluster_valid(tids, host_table),
+            cluster_valid=cluster_valid,
             slab_blocks=slab_blocks, block_gid0=cache.block_gid0,
             block_count=cache.block_count, slab_plane=cache.slab_plane,
             inv_norms=cache.inv_norms, nprobe=policy.nprobe, block_rows=br,

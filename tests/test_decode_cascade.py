@@ -263,13 +263,43 @@ def test_runtime_account_decode_ledger_and_registry():
         rt.account_decode(rplan, dim=32)
 
 
+def test_named_scopes_leave_the_kv_cascade_bit_identical(monkeypatch):
+    """`kv.*` named scopes are metadata: the paged, prescreened KV
+    cascade compiled with them returns what it returns without them, and
+    only the scoped program names its stages."""
+    import contextlib
+    cache, _, _ = make_cache(paged=True)
+    q = make_q()
+    L = jnp.asarray([T, 40], jnp.int32)
+    cfg = engine.KVCascadeConfig(top_k=8, npages=4, page_rows=8,
+                                 prescreen_c0=16)
+    policy = sparse_kv.kv_policy(cache, L)
+
+    def run():
+        def cascade(q, policy, cfg):        # a new function: a new trace
+            return engine._kv_cascade(q, policy, cfg)
+        fn = jax.jit(cascade, static_argnames=("cfg",))
+        return (np.asarray(fn(q, policy, cfg=cfg)),
+                fn.lower(q, policy, cfg=cfg).compile().as_text())
+    scoped, text = run()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain, plain_text = run()
+    np.testing.assert_array_equal(scoped, plain)
+    from repro.launch.hlo_analysis import op_scopes
+    assert set(op_scopes(text).values()) == {
+        "kv.QueryQuant", "kv.KVPagePrune", "kv.KVSignPrescreen",
+        "kv.KVApproxTopK", "kv.KVExactAttend"}
+    assert op_scopes(plain_text) == {}
+
+
 # -- end-to-end agent turn ---------------------------------------------------
 
 def test_rag_agent_turn_reports_uj_per_token():
     from repro.models import embedder as emb_mod
     from repro.models.common import ModelConfig
     from repro.models.registry import get_model
-    from repro.obs import MetricsRegistry
+    from repro.obs import MetricsRegistry, Tracer
     from repro.serve import (MultiTenantRAGPipeline, RAGAgent,
                              RuntimeConfig, ServingRuntime)
 
@@ -287,10 +317,10 @@ def test_rag_agent_turn_reports_uj_per_token():
     rng = np.random.default_rng(0)
     for t in range(2):
         pipe.ingest(t, rng.integers(0, 64, size=(6, 4)))
-    reg = MetricsRegistry()
+    reg, tracer = MetricsRegistry(), Tracer()
     rt = ServingRuntime(pipe.index,
                         RuntimeConfig(max_batch=2, auto_flush=False),
-                        registry=reg)
+                        registry=reg, tracer=tracer)
     agent = RAGAgent(pipeline=pipe, runtime=rt, top_k=16, npages=4,
                      prescreen_c0=24, page_rows=8)
     q = jnp.asarray(rng.integers(0, 64, size=(2, 4)))
@@ -303,6 +333,11 @@ def test_rag_agent_turn_reports_uj_per_token():
     hist = reg.snapshot()["histograms"]
     assert hist["energy_uj_per_query"]["count"] >= 2
     assert hist["energy_uj_per_token"]["count"] == 6
+    # the turn's phases are spans of the runtime's tracer, in order, the
+    # runtime's own inside the retrieval
+    assert [e.name for e in tracer.spans() if e.ph == "X"] == [
+        "runtime.launch", "runtime.retire", "rag.retrieve", "rag.prefill",
+        "rag.decode", "rag.account"]
     # greedy decoding is deterministic across turns (cached jits)
     rep2 = agent.turn(np.array([0, 1]), q, max_new=6, now=1.0)
     np.testing.assert_array_equal(np.asarray(rep.tokens),

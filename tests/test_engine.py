@@ -689,3 +689,34 @@ def test_prescreen_schedule_plan_ledger():
     base = RetrievalEngine(RetrievalConfig(k=5)).plan_for(db, 4, policy)
     base_s1 = [s for s in base.stages if s.name == "approx"][0].bytes_hbm
     assert pre.bytes_hbm + approx.bytes_hbm < base_s1
+
+
+def test_named_scopes_leave_the_cascade_bit_identical(monkeypatch):
+    """`cascade.*` named scopes are metadata: the prescreened slab
+    cascade compiled with them returns what it returns without them, and
+    only the scoped program names its stages."""
+    import contextlib
+
+    import jax
+    idx, policy, table, tids, q = make_slab_setup("cosine")
+    db = idx.arena.db()
+    view = policy.nprobe * table.shape[2] * policy.block_rows
+    cfg = dataclasses.replace(idx.cfg, prescreen_c0=view // 4)
+    pol = make_slab_policy(idx, policy, table, tids, 0.5)
+
+    def run():
+        def cascade(q, db, pol, cfg):       # a new function: a new trace
+            return engine_mod._cascade_batched(q, db, pol, cfg)
+        fn = jax.jit(cascade, static_argnames=("cfg",))
+        return fn(q, db, pol, cfg=cfg), fn.lower(q, db, pol, cfg=cfg) \
+            .compile().as_text()
+    scoped, text = run()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain, plain_text = run()
+    assert_results_equal(scoped, plain)
+    from repro.launch.hlo_analysis import op_scopes
+    assert set(op_scopes(text).values()) == {
+        "cascade.CentroidPrune", "cascade.SignPrescreen",
+        "cascade.ApproxScan", "cascade.ExactRescore"}
+    assert op_scopes(plain_text) == {}

@@ -1,7 +1,9 @@
+import re
+
 import jax
 import jax.numpy as jnp
 
-from repro.launch.hlo_analysis import analyze, parse_module
+from repro.launch.hlo_analysis import analyze, op_scopes, parse_module
 
 
 def _compile(f, *shapes):
@@ -49,3 +51,24 @@ def test_parse_module_splits_computations():
     c = _compile(f, (8,))
     comps = parse_module(c.as_text())
     assert len(comps) >= 2          # entry + loop body/cond
+
+
+def test_op_scopes_of_two_named_scopes():
+    def f(x, w):
+        with jax.named_scope("kv.Select"):
+            s = jnp.sort(x, axis=-1)
+        with jax.named_scope("kv.Attend"):
+            y = s @ w
+        return jnp.tanh(y)            # outside any scope
+    text = _compile(f, (8, 128), (128, 128)).as_text()
+    scopes = op_scopes(text)
+    assert set(scopes.values()) == {"kv.Select", "kv.Attend"}
+    ops = dict(re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$", text,
+                          re.M))
+    sorts = [n for n, line in ops.items() if " sort(" in line]
+    assert sorts and all(scopes[n] == "kv.Select" for n in sorts)
+    assert any(v == "kv.Attend" and ("dot(" in ops[n] or "fusion(" in ops[n])
+               for n, v in scopes.items())
+    # an op of no scope is left out; other prefixes read nothing
+    assert not any("tanh" in n for n in scopes)
+    assert op_scopes(text, prefixes=("cascade.",)) == {}

@@ -2,16 +2,16 @@
 
 Pins: balanced async spans (double-begin / orphan-end raise instead of
 silently corrupting the trace), injectable-clock determinism (the same
-simulated schedule yields a bit-identical event list), and the exact
-structure both exporters emit (Chrome trace_event µs scaling, JSON-lines
-record shapes).
+simulated schedule yields a bit-identical event list), the exact
+structure the Chrome exporter emits (trace_event µs scaling), and that
+every span, of a Tracer and of NULL_TRACER alike, lands on the JAX
+profiler's host plane.
 """
 import json
 
 import pytest
 
-from repro.obs import (NULL_TRACER, Tracer, chrome_trace,
-                       trace_jsonl_records, write_chrome_trace, write_jsonl)
+from repro.obs import NULL_TRACER, Tracer, chrome_trace, write_chrome_trace
 
 
 def test_async_span_lifecycle_balanced():
@@ -92,21 +92,52 @@ def test_chrome_trace_export_structure(tmp_path):
     assert json.loads(path.read_text())["traceEvents"] == doc["traceEvents"]
 
 
-def test_jsonl_export(tmp_path):
-    from repro.obs import MetricsRegistry
-    reg = MetricsRegistry()
-    reg.counter("hits").inc(2)
-    reg.histogram("lat").observe(0.5)
+def test_simulated_clock_events_are_pinned():
+    """The events of a simulated schedule, field by field: spans enter a
+    profiler annotation and record exactly what they recorded without
+    one."""
     tr = Tracer()
-    tr.instant("tick", now=1.0, step=3)
-    path = tmp_path / "events.jsonl"
-    n = write_jsonl(str(path), registry=reg, tracer=tr)
-    lines = [json.loads(line) for line in path.read_text().splitlines()]
-    assert n == len(lines) == 3
-    kinds = {(r["type"], r.get("kind", r.get("ph"))) for r in lines}
-    assert ("metric", "counter") in kinds and ("event", "i") in kinds
-    (ev,) = trace_jsonl_records(tr)
-    assert ev["attrs"] == {"step": 3} and ev["ts"] == 1.0
+    tr.begin("request", 0, now=0.5, tid=2, request=0)
+    with tr.span("runtime.launch", now=1.0, batch=1):
+        tr.instant("admit", now=1.0, tid=2, request=0)
+        with tr.span("runtime.preload", now=1.0):
+            pass
+    tr.end(0, now=1.0, launch=0)
+    got = [(e.name, e.ph, e.ts, e.tid, e.dur, e.attrs) for e in tr.spans()]
+    assert got == [("request", "B", 0.5, 2, None, {"request": 0}),
+                   ("admit", "i", 1.0, 2, None, {"request": 0}),
+                   ("runtime.preload", "X", 1.0, 0, 0.0, {}),
+                   ("runtime.launch", "X", 1.0, 0, 0.0, {"batch": 1}),
+                   ("request", "E", 1.0, 2, None, {"launch": 0})]
+
+
+def test_spans_reach_the_profiler_host_plane(tmp_path):
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("rag.retrieve"):
+            with NULL_TRACER.span("runtime.launch", now=3.0, batch=2):
+                jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans = {ev.name: (ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name in ("rag.retrieve", "runtime.launch")}
+    assert set(spans) == {"rag.retrieve", "runtime.launch"}
+    # the inner span lies inside the outer one, on one clock
+    assert spans["rag.retrieve"][0] <= spans["runtime.launch"][0]
+    assert spans["runtime.launch"][1] <= spans["rag.retrieve"][1]
+    # only the in-memory tracer kept an event
+    assert [e.name for e in tr.spans()] == ["rag.retrieve"]
+    assert NULL_TRACER.spans() == []
 
 
 def test_null_tracer_is_inert():

@@ -247,6 +247,35 @@ def test_cache_hit_path_bit_identical_to_miss_path():
                                    ref.candidate_indices[lane])
 
 
+def test_launch_phases_are_spans_on_the_runtime_clock():
+    """The cached launch path's host phases are spans of the runtime's
+    tracer: `runtime.launch` around the preload, the slab fill and the
+    tables, then `runtime.retire`, each stamped with the caller's `now`
+    (a simulated clock gives the same events on every replay)."""
+    from repro.obs import Tracer
+
+    def drive():
+        idx, q = make_clustered_index()
+        tracer = Tracer()
+        rt = ServingRuntime(idx, RuntimeConfig(max_batch=8,
+                                               cache_bytes=1 << 20,
+                                               auto_flush=False,
+                                               preload=True),
+                            tracer=tracer)
+        for t in range(4):
+            rt.submit(t, q[t][0], now=2.0)
+        rt.flush(now=3.0)
+        return [(e.name, e.ts, e.dur) for e in tracer.spans()
+                if e.ph == "X"]
+    spans = drive()
+    assert spans == [("runtime.preload", 3.0, 0.0),
+                     ("runtime.fill", 3.0, 0.0),
+                     ("runtime.tables", 3.0, 0.0),
+                     ("runtime.launch", 3.0, 0.0),
+                     ("runtime.retire", 3.0, 0.0)]
+    assert drive() == spans
+
+
 def test_cache_straddling_arena_mutation_evicts_stale_views():
     """Warm the cache, MUTATE the arena (insert + delete), query again:
     the stale generation's views must be evicted, and the results must
