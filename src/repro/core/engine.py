@@ -367,18 +367,30 @@ def stage0_sign_plane_batched_jnp(q_sign: jax.Array,
     return similarity.int_matmul(docs, q_sign)
 
 
+def _sign_dot_rows(docs: jax.Array, q_sign: jax.Array) -> jax.Array:
+    """Per-lane ±1 dots of gathered rows: docs (B, R, D) x q_sign (B, D)
+    -> (B, R), or x (B, M, D) -> (B, M, R) for M query rows a lane."""
+    if q_sign.ndim == 2:
+        return similarity.int_bmm(docs, q_sign)
+    return jax.vmap(similarity.int_bmm, in_axes=(None, 1),
+                    out_axes=1)(docs, q_sign)
+
+
 def stage0_sign_gather_batched_jnp(q_sign: jax.Array, sign_plane: jax.Array,
                                    block_ids: jax.Array, *,
                                    block_rows: int) -> jax.Array:
     """Block-gathered stage-0 sign scan (the prescreen's view), reference.
 
-    Same gather convention as stage1_gather_batched_jnp: rows past the
-    plane's end gather ZERO bytes, which unpack to all-(+1) rows scoring
-    ``sum_k sign(q_k)`` — identical on both backends and masked
-    downstream by membership (a sign score is never exposed unmasked).
+    q_sign (B, D), or (B, M, D) for M query rows scored against each
+    lane's one gather; returns (B, J * block_rows) or (B, M, J *
+    block_rows). Same gather convention as stage1_gather_batched_jnp:
+    rows past the plane's end gather ZERO bytes, which unpack to
+    all-(+1) rows scoring ``sum_k sign(q_k)`` — identical on both
+    backends and masked downstream by membership (a sign score is never
+    exposed unmasked).
     """
     gathered, _ = bitplanar.gather_blocks(sign_plane, block_ids, block_rows)
-    return similarity.int_bmm(bitplanar.unpack_sign_pm1(gathered), q_sign)
+    return _sign_dot_rows(bitplanar.unpack_sign_pm1(gathered), q_sign)
 
 
 def stage0_sign_gather_resident_jnp(q_sign: jax.Array, sign_plane: jax.Array,
@@ -389,7 +401,7 @@ def stage0_sign_gather_resident_jnp(q_sign: jax.Array, sign_plane: jax.Array,
     """
     rows = bitplanar.expand_block_rows(block_ids, block_rows)
     docs = bitplanar.unpack_sign_pm1(jnp.take(sign_plane, rows, axis=0))
-    return similarity.int_bmm(docs, q_sign)
+    return _sign_dot_rows(docs, q_sign)
 
 
 def stage2_rows_batched_jnp(q: jax.Array, msb_rows: jax.Array,
@@ -420,7 +432,8 @@ class StageFns:
     sign_gather / sign_gather_resident: the 1-bit sign-plane prescreen's
               block gathers, mirroring gather / gather_resident over the
               packed (N, D/8) sign plane — XNOR-popcount agreement in its
-              monotone ±1-dot form
+              monotone ±1-dot form; a (B, M, D) query scores M rows a
+              lane against the lane's one gather, giving (B, M, R)
     """
 
     plane: object
@@ -1280,7 +1293,8 @@ class RetrievalEngine:
 #                     (Quest-style page selection: per-page INT8 mean-key
 #                     centroids scored with the per-lane rows kernel)
 #   KVSignPrescreen — SignPrescreen over the pruned pages' 1-bit sign
-#                     plane via the scalar-prefetch stage-0 gather kernel
+#                     plane via the stage-0 gather kernel: one gather
+#                     per (batch, kv-head) lane, scored for its G heads
 #   KVApproxTopK    — ApproxScan: f32 query x MSB-nibble keys (x per-row
 #                     scale), GQA group-max, per-(batch, kv-head) top-k
 #   KVExactAttend   — ExactRescore-shaped terminal: reconstruct INT8 keys
@@ -1480,12 +1494,14 @@ class KVSignPrescreen:
 
     Streams only the packed sign plane of the selected pages (hd/8 bytes
     per position — 4x fewer than the MSB nibble stage) through the
-    stage-0 block-gather primitive over the FLAT cache plane (per-lane
-    block ids address (lane, page) pairs), group-maxes the ±1-dot
-    agreement across the lane's G query heads, and keeps the top-`c0`
-    members. Survivors are re-sorted into view order, so at
-    c0 >= view_rows the cascade is bit-identical to the no-prescreen
-    schedule — the same parity anchor the retrieval SignPrescreen pins.
+    stage-0 block-gather primitive over the FLAT cache plane: one lane
+    per (sequence, KV head), whose block ids address its selected
+    (lane, page) pairs, gathered ONCE and scored against all G query
+    heads of the lane's GQA group. The ±1-dot agreement is group-maxed
+    across those G heads and the top-`c0` members kept. Survivors are
+    re-sorted into view order, so at c0 >= view_rows the cascade is
+    bit-identical to the no-prescreen schedule — the same parity anchor
+    the retrieval SignPrescreen pins.
     """
 
     c0: int
@@ -1503,15 +1519,13 @@ class KVSignPrescreen:
         sign = pol.k_sign
         flat_sign = (bitplanar.sign_plane_from_msb(_kv_flat(pol.k_msb))
                      if sign is None else _kv_flat(sign))
-        q_sign = bitplanar.sign_pm1(ctx.q_codes).reshape(b * kh * g, hd)
+        q_sign = bitplanar.sign_pm1(ctx.q_codes).reshape(b * kh, g, hd)
         lane = (jnp.arange(b, dtype=jnp.int32)[:, None, None] * kh
                 + jnp.arange(kh, dtype=jnp.int32)[None, :, None])
         flat_pages = lane * (t // pr) + state.pages          # (B, KH, NP)
-        blk = jnp.broadcast_to(flat_pages[:, :, None, :],
-                               (b, kh, g, flat_pages.shape[-1]))
         scores = ctx.fns.sign_gather(q_sign, flat_sign,
-                                     blk.reshape(b * kh * g, -1),
-                                     block_rows=pr)          # (B', R) int32
+                                     flat_pages.reshape(b * kh, -1),
+                                     block_rows=pr)       # (B*KH, G, R)
         key = jnp.max(scores.reshape(b, kh, g, r), axis=2)   # (B, KH, R)
         key = jnp.where(state.member, key, INT32_MIN)
         _, sel = jax.lax.top_k(key, c0)                      # (B, KH, C0)

@@ -56,15 +56,19 @@ def pack_query_signs(q: jax.Array) -> jax.Array:
 
 def sign_bit_panels(q_sign: jax.Array, *, per_lane: bool = False
                     ) -> jax.Array:
-    """(B, D) int8 {+1, -1} -> the stage-0 kernels' bit panels: panel b
+    """{+1, -1} int8 queries -> the stage-0 kernels' bit panels: panel b
     holds dims k = 8 * j + b, so bit b of packed sign byte j pairs with
-    column j. Returns (8, B, D//8) (the batched kernel's shared panel) or,
-    with per_lane, (B, 8, 1, D//8) (the gather kernel's per-lane block)."""
-    b, d = q_sign.shape
-    bits = q_sign.reshape(b, d // 8, 8)
+    column j. A (B, D) batch gives (8, B, D//8), the batched kernel's
+    shared panel. With per_lane, the gather kernel's per-lane block:
+    (B, D) -> (B, 8, 1, D//8), one query row a lane, and (B, M, D) ->
+    (B, 8, M, D//8), M query rows a lane scored against the lane's one
+    gather (the KV prescreen's G query heads of a KV head)."""
     if per_lane:
-        return bits.transpose(0, 2, 1)[:, :, None, :]
-    return bits.transpose(2, 0, 1)
+        rows = q_sign[:, None, :] if q_sign.ndim == 2 else q_sign
+        b, m, d = rows.shape
+        return rows.reshape(b, m, d // 8, 8).transpose(0, 3, 1, 2)
+    b, d = q_sign.shape
+    return q_sign.reshape(b, d // 8, 8).transpose(2, 0, 1)
 
 
 def _pad_rows(a: jax.Array, mult: int) -> jax.Array:
@@ -255,16 +259,18 @@ def stage0_sign_scores_gather(q_sign: jax.Array, sign_plane: jax.Array,
                               ) -> jax.Array:
     """Kernel-backed drop-in for engine.stage0_sign_gather_batched_jnp.
 
-    q_sign: (B, D) int8 {+1, -1}; sign_plane: (N, D//8) packed uint8;
-    block_ids: (B, J) int32 clamped block ids — the SAME table the
-    stage-1 gather consumes. Returns (B, J * block_rows) int32. The
-    plane is zero-padded to a block multiple here (a no-op for arenas
-    sized to a block multiple); zero bytes unpack to all-+1 rows on both
-    backends and are masked downstream."""
+    q_sign: (B, D) int8 {+1, -1}, or (B, M, D) for M query rows a lane;
+    sign_plane: (N, D//8) packed uint8; block_ids: (B, J) int32 clamped
+    block ids — the SAME table the stage-1 gather consumes. Returns (B,
+    J * block_rows) int32, or (B, M, J * block_rows). The plane is
+    zero-padded to a block multiple here (a no-op for arenas sized to a
+    block multiple); zero bytes unpack to all-+1 rows on both backends
+    and are masked downstream."""
     plane = _pad_rows(sign_plane, block_rows)
-    return _s0.stage0_sign_gather_pallas(
+    out = _s0.stage0_sign_gather_pallas(
         sign_bit_panels(q_sign, per_lane=True), plane, block_ids,
         block_rows=block_rows)
+    return out[:, 0] if q_sign.ndim == 2 else out
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows",))
@@ -281,7 +287,7 @@ def stage0_sign_scores_gather_resident(q_sign: jax.Array, plane: jax.Array,
                          f"got {n} rows with block_rows={block_rows}")
     return _s0.stage0_sign_gather_pallas(
         sign_bit_panels(q_sign, per_lane=True), plane, block_ids,
-        block_rows=block_rows)
+        block_rows=block_rows)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_k",))

@@ -20,14 +20,23 @@ pinned in VMEM across the whole grid (query-stationary, exactly like the
 stage-1 kernels), and keeping it dense sidesteps a second in-kernel
 unpack.
 
-Two variants mirror the stage-1 pair:
+Two variants:
 
   * `stage0_sign_batched_pallas` — dense batched matmul over the whole
     plane, grid (num_blocks,), doc sign blocks streamed HBM->VMEM once
     per BATCH (the shape `stage1_int4_batched_pallas` uses);
-  * `stage0_sign_gather_pallas` — scalar-prefetch block gather driven by
-    the SAME per-lane block-id table as the stage-1 gather (the cluster
-    prune's output), so only selected clusters' sign blocks ever stream.
+  * `stage0_sign_gather_pallas` — block gather over a per-lane block-id
+    table, so only selected blocks' sign bytes ever stream. Each lane
+    carries M query rows (the retrieval cascade: M = 1, the cluster
+    prune's table that the stage-1 gather consumes too; the KV
+    prescreen: one lane per (sequence, KV head), its G query heads as
+    the M rows, so a GQA group's pages stream once). The grid is (lanes,
+    J / JB): the ids are scalar-prefetched, and each step starts the
+    manual DMAs of its JB selected blocks together before it waits on
+    any, then scores all M rows against all JB blocks in one MAC. JB is
+    every block of the lane while their unpacked tiles fit a fixed VMEM
+    budget (all 16 pages of a KV lane), else the largest divisor of J
+    that does — so the step count follows the lanes, not the pages.
 
 Zero bytes (the plane's padding rows and tombstoned rows) unpack to all
 +1 dims and score ``sum_k sign(q_k)`` — NOT zero. That is the shared
@@ -53,10 +62,16 @@ from repro.kernels.stage1_int4 import mac_nt
 # per-device choice.
 DEFAULT_BLOCK_N = 1024
 
+# VMEM one grid step of the block gather may fill with unpacked sign
+# blocks (int32 tiles): every block of a KV lane (16 pages of 8 rows, 64
+# KiB) and half of a retrieval lane's 128 probed 32-row blocks fit.
+GATHER_STEP_VMEM_BYTES = 1 << 20
+
 
 def score_sign_rows(q_bits: jax.Array, block_u8: jax.Array) -> jax.Array:
     """q_bits (8, M, D8) int8 bit panels; block_u8 (BN, D8) packed sign
-    bytes -> (M, BN) int32 ``sum_k sign(q_k) * sign(d_k)``.
+    bytes (uint8, or already widened to int32) -> (M, BN) int32
+    ``sum_k sign(q_k) * sign(d_k)``.
 
     Panel b holds the query dims k = 8 * j + b (byte-major then bit,
     matching `bitplanar.pack_sign_plane`), so bit b of doc byte j pairs
@@ -104,13 +119,40 @@ def stage0_sign_batched_pallas(q_bits: jax.Array, sign_plane: jax.Array, *,
     )(q_bits, sign_plane)
 
 
-def _stage0_gather_kernel(ids_ref, q_ref, plane_ref, out_ref):
-    """ids_ref: (B, J) int32 prefetched block ids (consumed by the
-    BlockSpec index_maps); q_ref: (1, 8, 1, D8) int8 lane bit panels;
-    plane_ref: (BR, D8) uint8 — the sign block the index_map selected;
-    out: (1, 1, 1, BR)."""
-    del ids_ref  # only read by the BlockSpec index_maps
-    out_ref[0, 0] = score_sign_rows(q_ref[0], plane_ref[...])
+def gather_blocks_per_step(num_blocks: int, block_rows: int,
+                           d8: int) -> int:
+    """Blocks one grid step of the gather holds: all of a lane's blocks
+    when their unpacked int32 tiles fit `GATHER_STEP_VMEM_BYTES`, else the
+    largest divisor of `num_blocks` that does (one block at the least).
+    A block's footprint counts its rows and lanes padded to the (8, 128)
+    int32 tile it occupies once unpacked."""
+    tile_bytes = (-(-block_rows // 8) * 8) * (-(-d8 // 128) * 128) * 4
+    cap = max(1, GATHER_STEP_VMEM_BYTES // tile_bytes)
+    return max(k for k in range(1, min(num_blocks, cap) + 1)
+               if num_blocks % k == 0)
+
+
+def _stage0_gather_kernel(ids_ref, q_ref, plane_hbm, out_ref, buf, sem):
+    """ids_ref: (B, J) int32 prefetched block ids (SMEM); q_ref: (1, 8, M,
+    W) int8 lane bit panels; plane_hbm: (N / BR, BR, W) uint8 sign blocks,
+    left in HBM; out: (1, 1, M, JB * BR); buf: (JB, BR, W) uint8 VMEM;
+    sem: one DMA semaphore shared by the step's copies. W is D8 padded
+    with zero bytes to whole 128-lane rows.
+
+    Every copy of the step's JB blocks starts before any is waited on, so
+    the lane's blocks are in flight together; then one MAC scores the M
+    query rows against all JB * BR gathered rows."""
+    i, s = pl.program_id(0), pl.program_id(1)
+    jb, br, w = buf.shape
+    copies = [pltpu.make_async_copy(plane_hbm.at[ids_ref[i, s * jb + k]],
+                                    buf.at[k], sem)
+              for k in range(jb)]
+    for c in copies:
+        c.start()
+    for c in copies:
+        c.wait()
+    rows = buf[...].astype(jnp.int32).reshape(jb * br, w)
+    out_ref[0, 0] = score_sign_rows(q_ref[0], rows)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -118,33 +160,52 @@ def stage0_sign_gather_pallas(q_bits: jax.Array, sign_plane: jax.Array,
                               block_ids: jax.Array, *,
                               block_rows: int,
                               interpret: bool | None = None) -> jax.Array:
-    """Block-gathered stage 0: q_bits (B, 8, 1, D//8) int8 {+1, -1} lane
-    bit panels (`ops.sign_bit_panels(..., per_lane=True)`); sign_plane
-    (N, D//8) uint8 with N % block_rows == 0 (zero-padded); block_ids
-    (B, J) int32 ids in [0, N / block_rows) — the SAME clamped per-lane
-    table the stage-1 gather consumes, so the prescreen's view geometry
-    can never drift from the scan it is pruning. Returns (B, J *
-    block_rows) int32 sign-agreement scores in block-table order. ONE
-    launch, grid (B, J), scalar-prefetched ids: only selected blocks
-    ever stream from HBM."""
+    """Block-gathered stage 0: q_bits (B, 8, M, D//8) int8 {+1, -1} bit
+    panels, M query rows per lane (`ops.sign_bit_panels(..., per_lane=
+    True)`); sign_plane (N, D//8) uint8 with N % block_rows == 0
+    (zero-padded); block_ids (B, J) int32 ids in [0, N / block_rows) —
+    for the retrieval cascade the SAME clamped per-lane table the
+    stage-1 gather consumes (M = 1), for the KV prescreen one row per
+    (sequence, KV head) with its G query heads as the M rows. Returns
+    (B, M, J * block_rows) int32 sign-agreement scores in block-table
+    order.
+
+    ONE launch, grid (B, J / JB) with JB = `gather_blocks_per_step`:
+    the ids are scalar-prefetched, each step copies its JB selected
+    blocks HBM->VMEM with manual DMAs all in flight at once, so only
+    selected blocks ever stream and the step count does not grow with
+    the blocks a lane selects while they fit the VMEM budget."""
     n, d8 = sign_plane.shape
-    b, j = block_ids.shape
+    b, _, m, _ = q_bits.shape
+    j = block_ids.shape[1]
     assert n % block_rows == 0, (n, block_rows)
+    jb = gather_blocks_per_step(j, block_rows, d8)
+    steps = j // jb
+    # Mosaic slices an HBM ref only along whole 128-lane rows, and XLA
+    # already lays a narrower byte plane out padded to 128 lanes: pad
+    # plane and query panels with zero columns (zero query bits add 0).
+    wide = -(-d8 // 128) * 128
+    if wide != d8:
+        sign_plane = jnp.pad(sign_plane, ((0, 0), (0, wide - d8)))
+        q_bits = jnp.pad(q_bits, ((0, 0), (0, 0), (0, 0), (0, wide - d8)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, j),
+        grid=(b, steps),
         in_specs=[
-            pl.BlockSpec((1, 8, 1, d8), lambda i, jj, ids: (i, 0, 0, 0)),
-            pl.BlockSpec((block_rows, d8),
-                         lambda i, jj, ids: (ids[i, jj], 0)),
+            pl.BlockSpec((1, 8, m, wide), lambda i, s, ids: (i, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, block_rows),
-                               lambda i, jj, ids: (i, jj, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, m, jb * block_rows),
+                               lambda i, s, ids: (i, s, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((jb, block_rows, wide), jnp.uint8),
+                        pltpu.SemaphoreType.DMA(())],
     )
     out = pl.pallas_call(
         _stage0_gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, j, 1, block_rows), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, steps, m, jb * block_rows),
+                                       jnp.int32),
         interpret=resolve_interpret(interpret),
-    )(block_ids, q_bits, sign_plane)
-    return out.reshape(b, j * block_rows)
+    )(block_ids, q_bits,
+      sign_plane.reshape(n // block_rows, block_rows, wide))
+    return out.transpose(0, 2, 1, 3).reshape(b, m, j * block_rows)

@@ -83,19 +83,34 @@ def test_paged_full_coverage_degenerates_to_legacy(backend):
     np.testing.assert_array_equal(np.asarray(ps), np.asarray(ref))
 
 
-@pytest.mark.parametrize("lengths", [(5, 23), (0, 0), (64, 1)])
-def test_pruned_cascade_jnp_vs_pallas_bit_parity(lengths):
+# The agent cell's decode: qwen2-0.5b's 14 query heads over 2 KV heads of
+# 64, 4 sequences of 576 positions in 8-row pages, 16 pages kept, 64 rows
+# through the sign prescreen, top 32 attended.
+AGENT_CELL = dict(b=4, t=576, kh=2, hd=64, h=14, top_k=32,
+                  stages=({"npages": 16, "prescreen_c0": 64},))
+
+
+@pytest.mark.parametrize("lengths,geom", [
+    ((5, 23), {}), ((0, 0), {}), ((64, 1), {}),
+    ((517, 0, 576, 131), AGENT_CELL)],
+    ids=["lengths0", "lengths1", "lengths2", "agent_cell"])
+def test_pruned_cascade_jnp_vs_pallas_bit_parity(lengths, geom):
     """The PRUNED schedules (partial page coverage, sign prescreen) have
     no legacy twin; their contract is backend equivalence — the Pallas
     prune/prescreen kernels must select the same pages/rows as the jnp
-    reference fns, making the whole cascade bit-identical."""
-    cache, _, _ = make_cache(paged=True)
-    q = make_q()
+    reference fns, making the whole cascade bit-identical. The agent
+    cell's case runs its geometry, with a length ending mid-page and a
+    length of 0."""
+    shape = {k: geom[k] for k in ("b", "t", "kh", "hd") if k in geom}
+    cache, _, _ = make_cache(paged=True, **shape)
+    q = make_q(b=geom.get("b", B), h=geom.get("h", H), hd=geom.get("hd", HD))
     L = jnp.asarray(lengths, jnp.int32)
-    for kwargs in ({"npages": 4}, {"npages": 6, "prescreen_c0": 24}):
-        a = sparse_kv.sparse_decode_attention(q, cache, L, top_k=8,
+    top_k = geom.get("top_k", 8)
+    for kwargs in geom.get("stages", ({"npages": 4},
+                                      {"npages": 6, "prescreen_c0": 24})):
+        a = sparse_kv.sparse_decode_attention(q, cache, L, top_k=top_k,
                                               backend="jnp", **kwargs)
-        b = sparse_kv.sparse_decode_attention(q, cache, L, top_k=8,
+        b = sparse_kv.sparse_decode_attention(q, cache, L, top_k=top_k,
                                               backend="pallas", **kwargs)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         assert not np.any(np.isnan(np.asarray(a)))

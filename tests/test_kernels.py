@@ -319,6 +319,66 @@ def test_stage0_sign_gather_kernels_two_region_slab(n, d, b, j, br):
     np.testing.assert_array_equal(np.asarray(lean_r), np.asarray(got))
 
 
+def _kv_cell_block_table(lengths, kh, pages, npages, rng):
+    """Per (sequence, KV head) lane: `npages` ascending pages, drawn from
+    the pages a sequence's `length` reaches (the page prune's valid set),
+    filled with the lowest invalid pages when too few are valid (what
+    top_k of an all -inf key gives), as flat block ids lane * pages + p."""
+    table = []
+    for b, length in enumerate(lengths):
+        valid = -(-length // 8)
+        for h in range(kh):
+            if valid >= npages:
+                sel = np.sort(rng.choice(valid, npages, replace=False))
+            else:
+                sel = np.arange(npages)
+            table.append((b * kh + h) * pages + sel)
+    return np.stack(table).astype(np.int32)
+
+
+@pytest.mark.parametrize("lengths", [(517, 0, 576, 131), (576, 9, 0, 64)])
+def test_stage0_sign_gather_gqa_shared_matches_per_head_ref(lengths):
+    """The KV prescreen's gather at the agent cell's shapes (B 4, KH 2,
+    G 7, hd 64, T 576, 16 of 72 pages): one gather per (sequence, KV
+    head) lane, scored against the lane's 7 query heads, equals the
+    per-query-head oracle bit for bit on every head. Lengths end
+    mid-page and at 0; the last lane's table ends on the plane's last
+    block."""
+    from repro.core.bitplanar import sign_plane_from_msb
+    from repro.core.engine import stage0_sign_gather_batched_jnp
+    bsz, kh, g, hd, t, pr, npages = 4, 2, 7, 64, 576, 8, 16
+    pages = t // pr
+    rng = np.random.default_rng(sum(lengths))
+    k_msb = rng.integers(0, 256, (bsz * kh * t, hd // 2)).astype(np.uint8)
+    plane = sign_plane_from_msb(jnp.asarray(k_msb))           # (4608, 8)
+    table = _kv_cell_block_table(lengths, kh, pages, npages, rng)
+    table[-1, -1] = bsz * kh * pages - 1                      # last block
+    table = jnp.asarray(np.sort(table, axis=1))
+    q = jnp.asarray(np.where(rng.random((bsz * kh, g, hd)) < 0.5,
+                             -1, 1).astype(np.int8))
+    got = ops.stage0_sign_scores_gather(q, plane, table, block_rows=pr)
+    lean = stage0_sign_gather_batched_jnp(q, plane, table, block_rows=pr)
+    assert got.shape == (bsz * kh, g, npages * pr)
+    for head in range(g):
+        want = ref.stage0_sign_gather_ref(q[:, head], plane, table, pr)
+        np.testing.assert_array_equal(np.asarray(got[:, head]),
+                                      np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(lean[:, head]),
+                                      np.asarray(want))
+
+
+@pytest.mark.parametrize("j,block_rows,d8,want", [
+    (16, 8, 8, 16),        # a KV lane: every page in one step
+    (128, 32, 64, 64),     # a retrieval lane: half its blocks a step
+    (7, 32, 64, 7), (96, 32, 512, 16), (5, 512, 512, 1)])
+def test_stage0_gather_blocks_per_step(j, block_rows, d8, want):
+    """All of a lane's blocks in one step while their unpacked tiles fit
+    the budget, else the largest divisor of J that fits, never zero."""
+    from repro.kernels.stage0_sign import gather_blocks_per_step
+    got = gather_blocks_per_step(j, block_rows, d8)
+    assert got == want and j % got == 0
+
+
 def test_stage0_sign_plane_matches_msb_derivation():
     """pack_sign_plane(codes) == sign_plane_from_msb(pack_nibble_planes'
     msb): the identity that lets the serving slab derive its combined

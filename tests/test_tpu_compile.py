@@ -38,8 +38,9 @@ SLAB = 4_096               # hot-cluster slab rows appended to the plane
 CLUSTERS = 256             # codebook rows scored by the centroid prune
 CANDIDATES = 64            # stage-2 rescore rows per lane
 WINDOW = 8_192             # one tenant's arena window (8 x 8,192 docs)
-# KV cascade of one qwen2-0.5b decode step at batch 4
-HD, KVH, G, KV_B, KV_T, PAGE = 64, 2, 7, 4, 256, 8
+# KV cascade of one qwen2-0.5b decode step at batch 4 (the agent cell:
+# 512-position prompts + 64 tokens = 576 positions, 16 pages kept)
+HD, KVH, G, KV_B, KV_T, PAGE, KV_KEPT = 64, 2, 7, 4, 576, 8, 16
 KV_LANES = KV_B * KVH * G
 
 
@@ -118,8 +119,15 @@ CASES = {
     "kv_gather_hd64": (s0.stage0_sign_gather_pallas,
                        [_i8(KV_LANES, 8, 1, HD // 8),
                         _u8(KV_B * KVH * KV_T, HD // 8),
-                        _i32(KV_LANES, 16)],
+                        _i32(KV_LANES, KV_KEPT)],
                        dict(block_rows=PAGE)),
+    # the KV prescreen's call: one lane per (sequence, KV head), its G
+    # query heads as G rows against one gather of its kept pages
+    "kv_gather_gqa_shared": (s0.stage0_sign_gather_pallas,
+                             [_i8(KV_B * KVH, 8, G, HD // 8),
+                              _u8(KV_B * KVH * KV_T, HD // 8),
+                              _i32(KV_B * KVH, KV_KEPT)],
+                             dict(block_rows=PAGE)),
     # kernels off the served path, kept compilable all the same
     "stage1_single": (s1.stage1_int4_pallas,
                       [_i8(2, D // 2), _u8(N, D // 2)],
